@@ -119,6 +119,9 @@ def main() -> None:
                          "the machine-readable timings (CI artifacts)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_common_feature,
         bench_division,

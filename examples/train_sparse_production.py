@@ -9,7 +9,7 @@ Theta (1e6 x 8) with L1+L2,1 sparsity.
 
 Execution: the whole job rides the FUSED sparse kernel package
 (`repro.kernels.lsplm_sparse_fused`) — a pipelined block-DMA Pallas
-gather-matmul on TPU (scalar-prefetched ids, double-buffered K-row
+gather-matmul on TPU (per-tile SMEM ids, double-buffered K-row
 blocks, Theta in HBM), K-chunked `lax.scan` accumulation on CPU/GPU, and
 a custom-VJP backward scheduled by per-batch TRANSPOSE PLANS
 (`generate_sparse` attaches them): the id->entries sort happens once on

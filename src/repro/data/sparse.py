@@ -16,7 +16,7 @@ This is TPU-native (dense gather + reductions — no hash maps, DESIGN.md
 
 Execution path: everything here rides the FUSED sparse kernel package
 (``repro.kernels.lsplm_sparse_fused``) — a pipelined block-DMA Pallas
-gather-matmul on TPU (scalar-prefetched ids, double-buffered K-row
+gather-matmul on TPU (per-tile SMEM ids, double-buffered K-row
 blocks), a K-chunked ``lax.scan`` accumulation elsewhere, and a
 ``jax.custom_vjp`` whose backward is the transposed scatter. The old
 ``take``+einsum formulation, which materialises the (N, K, 2m) gather

@@ -105,9 +105,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                pltpu.TPUCompilerParams)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

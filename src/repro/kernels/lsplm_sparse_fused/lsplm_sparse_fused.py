@@ -1,21 +1,25 @@
-"""Fused sparse LS-PLM forward kernel — pipelined block-DMA gather + Eq. 2.
+"""Fused sparse LS-PLM forward kernel — pipelined row-DMA gather + Eq. 2.
 
 The paper's production inputs are one-hot/multi-hot id lists over millions
 of columns (§2, §3.2); a dense (B, d) batch never exists. This kernel
 computes p(y=1|x) straight from padded-COO (ids, vals) in one pass per
-batch tile, with the row gathers organised as a true DMA pipeline:
+batch tile, with the row gathers organised as a DMA pipeline:
 
-  * ids are a SCALAR-PREFETCH operand (``PrefetchScalarGridSpec``): they
-    land in SMEM before the kernel body runs, so every DMA's source row
-    is known without touching VMEM — the requirement for issuing copies
-    ahead of the compute that consumes them.
-  * Theta (D, 2m) stays in HBM; the K id slots of each sample are
-    processed in K-ROW BLOCKS of ``block_k`` rows. Two (block_k, 2m)
-    VMEM buffers double-buffer the stream: while block t is being
-    contracted against its vals chunk, the ``block_k`` row copies of
-    block t+1 are already in flight — gathers for the next block overlap
-    the matmul of the current one, across sample boundaries too (the
-    flat pipeline index runs over the whole tile).
+  * each tile's ids and vals arrive as 1-D SMEM blocks (the grid
+    pipeline copies tile i+1's while tile i runs), so every DMA's source
+    row and every slot's value are scalars the kernel reads without
+    touching VMEM. SMEM holds two tiles at a time, never the batch.
+  * the gathered table stays in HBM in a LANE-ALIGNED ROW LAYOUT: one
+    128-lane row per id (``lane_rows``), because the TPU's DMA engine
+    moves whole lane-tiles and cannot slice a 2m-wide row out of a
+    (D, 2m) array. The K id slots of each sample are processed in K-ROW
+    BLOCKS of ``block_k`` rows; two (block_k, 128) VMEM buffers
+    double-buffer the stream: while block t is contracted, the row
+    copies of block t+1 are already in flight, across sample boundaries
+    too (the flat pipeline index runs over the whole tile).
+  * the contraction is ``block_k`` scalar-times-row multiply-adds on the
+    vector unit into a (1, 128) register carry; a sample's finished
+    carry is stored as one row of a (block_n, 128) VMEM tile.
   * pad-id rows (id == D-1) are SKIPPED: no HBM DMA is issued; the
     buffer row is zeroed in place instead, so a pad slot contracts
     exactly like the zero pad row it aliases (even if its val is not 0,
@@ -24,25 +28,27 @@ batch tile, with the row gathers organised as a true DMA pipeline:
     sample collapse onto their first slot with summed values, freed
     slots become pad), hot features are fetched once per sample and
     ragged tails cost nothing.
-  * the softmax-dot-sigmoid fusion (Eq. 2) runs in-register on the
-    accumulated z tile; only (BT,) probabilities and the (BT, 2m) region
-    logits are written back (z is the residual the custom VJP needs).
+  * the softmax-dot-sigmoid fusion (Eq. 2) runs on the finished z tile;
+    only (BT,) probabilities and the (BT, 2m) region logits are written
+    back (z is the residual the custom VJP needs).
+
+The int8-native variant gathers from a table of int8 codes packed four
+rows to a 128-lane int32 row (``int8_plane_rows``: byte b of every lane
+of packed row r holds row 4r+b), unpacks the plane with two shifts, and
+takes the per-row scale folded into the slot's value — fp32 rows never
+exist in HBM, and each row DMA serves four codes rows' worth of bytes.
 
 Grid: (N/block_n,) over batch tiles. Theta must carry the zero pad row
 (id == D-1); ``ops.pad_theta`` provides it.
 
 VMEM/SMEM sizing rule (what bounds the block sizes):
 
-    VMEM  ~=  2 * block_k * 2m * 4        (double buffers)
-            + block_n * K_pad * 4          (vals tile)
-            + block_n * (2m + 1) * 4       (z + p tiles)
-    SMEM  ~=  N_pad * K_pad * 4            (prefetched ids, whole batch)
+    VMEM  ~=  2 * block_k * 128 * 4        (double buffers)
+            + block_n * 128 * 4            (z accumulator tile)
+            + 2 * block_n * (2m + 1) * 4   (z + p output tiles)
+    SMEM  ~=  2 * 2 * block_n * K_pad * 4  (ids + vals, double-buffered)
 
-so block_n * K and block_k * 2m are the knobs; ids SMEM residency bounds
-the rows per ``pallas_call`` — CALLERS must slice batches whose
-N_pad * K_pad * 4 bytes exceed SMEM into separate calls (no automatic
-slabbing exists yet; see ROADMAP's TPU bring-up item). Theta itself
-never enters VMEM (d is HBM-bounded: a (1e6, 24) fp32 Theta is 96 MB).
+Theta itself never enters VMEM (d is HBM-bounded).
 
 (block_n, block_k) are RESOLVED FROM THE AUTOTUNE TABLE (``repro.tune``,
 kernel key ``"fused_fwd"``) when the public ops are called with the
@@ -50,15 +56,10 @@ knobs left at None — the sizing rule above bounds the sweep grid, the
 sweep (``python -m repro.tune.sweep``) picks within it, parity-gated
 against the ref oracle per config. Explicit kwargs always win.
 
-Coverage: CI validates this kernel in INTERPRET mode (no TPU runners),
-which exercises the full pipeline logic — scalar-prefetched indexing,
-conditional skip DMAs, buffer rotation, cross-sample chunk flattening.
-The compiled Mosaic path follows the standard prefetch+double-buffer
-recipe (see the Pallas guide's "Double Buffering" pattern); first-TPU
-bring-up runs ``tests/test_kernel_parity.py`` (``REPRO_KERNEL_PARITY=1``
-— ``mode="kernel"`` vs ``mode="interpret"``) and then regenerates the
-TPU table with ``python -m repro.tune.sweep --mode kernel --out
-src/repro/tune/tables/tpu.json``.
+Coverage: the CPU test suite runs this kernel in INTERPRET mode;
+``tests/test_tpu_compile.py`` compiles it for a described TPU v5e at
+production width, and ``chip_smoke.py`` runs it on the chip against the
+``ref.py`` oracle.
 """
 from __future__ import annotations
 
@@ -70,172 +71,154 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(ids_ref, vals_ref, theta_ref, p_ref, z_ref, bufs, sems, *,
-            m: int, block_n: int, block_k: int, nkb: int, skip_id: int):
+LANES = 128
+
+
+def lane_width(m2: int) -> int:
+    """Lanes per gathered row: 2m rounded up to whole 128-lane tiles."""
+    return -(-m2 // LANES) * LANES
+
+
+def lane_rows(theta: jax.Array) -> jax.Array:
+    """(D, 2m) -> (D, lane_width(2m)): each row right-padded with zeros to
+    whole lane tiles, the layout the row DMAs can address."""
+    m2 = theta.shape[1]
+    return jnp.pad(theta, ((0, 0), (0, lane_width(m2) - m2)))
+
+
+def int8_plane_rows(codes: jax.Array) -> jax.Array:
+    """(D, 2m) int8 -> (ceil(D/4), lane_width(2m)) int32: byte b of every
+    lane of packed row r holds codes row 4r+b (little-endian planes)."""
+    D, m2 = codes.shape
+    d4 = -(-D // 4)
+    c = jnp.pad(codes.astype(jnp.int32) & 0xFF, ((0, 4 * d4 - D), (0, 0)))
+    # shifts, not a bitcast: XLA's constant folder packs bitcast bytes in
+    # another order than its runtime does when the codes are constants
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.int32)[None, :, None]
+    word = jnp.sum(jnp.left_shift(c.reshape(d4, 4, m2), shifts), axis=1)
+    return jnp.pad(word, ((0, 0), (0, lane_width(m2) - m2)))
+
+
+def varying_axes(*xs) -> frozenset:
+    """The manual mesh axes any of ``xs`` varies over (empty outside a
+    ``shard_map``): a kernel's outputs inside one vary like its inputs."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+
+def _kernel(ids_ref, vals_ref, table_ref, p_ref, z_ref, acc_ref, bufs, sems,
+            *, m: int, block_n: int, block_k: int, nkb: int, skip_id: int,
+            planes: int):
     """One batch tile: T = block_n * nkb pipelined K-row blocks."""
-    pid = pl.program_id(0)
     T = block_n * nkb
+    width = bufs.shape[-1]
 
-    @pl.when(pid == 0)
-    def _zero_buffers():  # never read uninitialised VMEM on skipped slots
-        bufs[...] = jnp.zeros_like(bufs)
-
-    def row_dma(t, slot, j):
-        n = pid * block_n + t // nkb
-        k = jax.lax.rem(t, nkb) * block_k + j
-        return pltpu.make_async_copy(
-            theta_ref.at[ids_ref[n, k]], bufs.at[slot, j], sems.at[slot, j])
+    def row_dma(slot, j, rid):
+        src = rid // planes if planes > 1 else rid
+        return pltpu.make_async_copy(table_ref.at[pl.ds(src, 1)],
+                                     bufs.at[slot, pl.ds(j, 1)],
+                                     sems.at[slot, j])
 
     def start(t, slot):
         for j in range(block_k):
-            n = pid * block_n + t // nkb
-            k = jax.lax.rem(t, nkb) * block_k + j
+            rid = ids_ref[t * block_k + j]
 
-            @pl.when(ids_ref[n, k] != skip_id)
+            @pl.when(rid != skip_id)
             def _():
-                row_dma(t, slot, j).start()
+                row_dma(slot, j, rid).start()
 
             # skipped slots must still contract like the zero pad row —
-            # zero the buffer row (VMEM-only store; slot (t+1)%2 is idle
-            # while step t computes, so this never races the matmul)
-            @pl.when(ids_ref[n, k] == skip_id)
+            # zero the buffer row (VMEM-only store into the slot the
+            # current step is not reading, so it never races the contraction)
+            @pl.when(rid == skip_id)
             def _():
-                bufs[slot, j, :] = jnp.zeros_like(bufs[slot, j, :])
+                bufs[slot, pl.ds(j, 1), :] = jnp.zeros((1, width), bufs.dtype)
 
     def wait(t, slot):
         for j in range(block_k):
-            n = pid * block_n + t // nkb
-            k = jax.lax.rem(t, nkb) * block_k + j
+            rid = ids_ref[t * block_k + j]
 
-            @pl.when(ids_ref[n, k] != skip_id)
+            @pl.when(rid != skip_id)
             def _():
-                row_dma(t, slot, j).wait()
+                row_dma(slot, j, rid).wait()
 
     start(0, 0)
 
-    def pipeline_step(t, carry):
+    def pipeline_step(t, acc):
         slot = jax.lax.rem(t, 2)
 
         @pl.when(t + 1 < T)
         def _prefetch_next():  # overlaps the contraction below
-            start(t + 1, jax.lax.rem(t + 1, 2))
+            start(t + 1, 1 - slot)
 
         wait(t, slot)
-        n = t // nkb
         b = jax.lax.rem(t, nkb)
-        vchunk = vals_ref[n, pl.ds(b * block_k, block_k)]
-        partial = jnp.dot(
-            vchunk.astype(jnp.float32),
-            bufs[slot].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
+        acc = jnp.where(b == 0, jnp.zeros_like(acc), acc)
+        for j in range(block_k):
+            e = t * block_k + j
+            row = bufs[slot, pl.ds(j, 1), :]
+            if planes > 1:  # int8 plane (id % 4) of the packed int32 row
+                shift = 8 * (planes - 1 - jax.lax.rem(ids_ref[e], planes))
+                row = jax.lax.shift_right_arithmetic(
+                    jax.lax.shift_left(row, jnp.full_like(row, shift)),
+                    jnp.full_like(row, 8 * (planes - 1)))
+            acc = acc + vals_ref[e] * row.astype(jnp.float32)
 
-        @pl.when(b == 0)
+        @pl.when(b == nkb - 1)
         def _():
-            z_ref[n, :] = partial
+            acc_ref[pl.ds(t // nkb, 1), :] = acc
 
-        @pl.when(b != 0)
-        def _():
-            z_ref[n, :] = z_ref[n, :] + partial
+        return acc
 
-        return carry
+    jax.lax.fori_loop(0, T, pipeline_step,
+                      jnp.zeros((1, width), jnp.float32))
 
-    jax.lax.fori_loop(0, T, pipeline_step, 0)
-
-    z = z_ref[...]
+    z = acc_ref[:, :2 * m]
+    z_ref[...] = z
     gate = jax.nn.softmax(z[:, :m], axis=-1)
     fit = jax.nn.sigmoid(z[:, m:])
     p_ref[...] = jnp.sum(gate * fit, axis=-1, keepdims=True).astype(p_ref.dtype)
 
 
-def _kernel_int8(ids_ref, vals_ref, codes_ref, scales_ref, p_ref, z_ref,
-                 bufs, sbufs, sems, ssems, *,
-                 m: int, block_n: int, block_k: int, nkb: int, skip_id: int):
-    """Int8-native batch tile: same pipeline as :func:`_kernel`, but the
-    row DMAs move int8 CODE rows (4x fewer bytes than fp32) plus their
-    (1,) fp32 scales; the scale is applied in VMEM right before the
-    contraction — ``rows = codes.astype(f32) * scale`` — so fp32 rows
-    never exist anywhere, HBM or VMEM, only the (block_k, 2m) working
-    set of the current pipeline step."""
-    pid = pl.program_id(0)
-    T = block_n * nkb
-
-    @pl.when(pid == 0)
-    def _zero_buffers():  # never read uninitialised VMEM on skipped slots
-        bufs[...] = jnp.zeros_like(bufs)
-        sbufs[...] = jnp.zeros_like(sbufs)
-
-    def row_dmas(t, slot, j):
-        n = pid * block_n + t // nkb
-        k = jax.lax.rem(t, nkb) * block_k + j
-        rid = ids_ref[n, k]
-        return (pltpu.make_async_copy(
-                    codes_ref.at[rid], bufs.at[slot, j], sems.at[slot, j]),
-                pltpu.make_async_copy(
-                    scales_ref.at[rid], sbufs.at[slot, j], ssems.at[slot, j]))
-
-    def start(t, slot):
-        for j in range(block_k):
-            n = pid * block_n + t // nkb
-            k = jax.lax.rem(t, nkb) * block_k + j
-
-            @pl.when(ids_ref[n, k] != skip_id)
-            def _():
-                for dma in row_dmas(t, slot, j):
-                    dma.start()
-
-            # a skipped slot must contract like the zero pad row: zero its
-            # SCALE — codes are int8 (always finite), so stale codes times
-            # an exact-0.0 scale contract to exact 0.0
-            @pl.when(ids_ref[n, k] == skip_id)
-            def _():
-                sbufs[slot, j, :] = jnp.zeros_like(sbufs[slot, j, :])
-
-    def wait(t, slot):
-        for j in range(block_k):
-            n = pid * block_n + t // nkb
-            k = jax.lax.rem(t, nkb) * block_k + j
-
-            @pl.when(ids_ref[n, k] != skip_id)
-            def _():
-                for dma in row_dmas(t, slot, j):
-                    dma.wait()
-
-    start(0, 0)
-
-    def pipeline_step(t, carry):
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < T)
-        def _prefetch_next():  # overlaps the contraction below
-            start(t + 1, jax.lax.rem(t + 1, 2))
-
-        wait(t, slot)
-        n = t // nkb
-        b = jax.lax.rem(t, nkb)
-        vchunk = vals_ref[n, pl.ds(b * block_k, block_k)]
-        # the scale epilogue: int8 codes -> fp32 rows, in VMEM, fused
-        # into this step's contraction (pad slots have scale == 0.0)
-        rows = bufs[slot].astype(jnp.float32) * sbufs[slot]
-        partial = jnp.dot(vchunk.astype(jnp.float32), rows,
-                          preferred_element_type=jnp.float32)
-
-        @pl.when(b == 0)
-        def _():
-            z_ref[n, :] = partial
-
-        @pl.when(b != 0)
-        def _():
-            z_ref[n, :] = z_ref[n, :] + partial
-
-        return carry
-
-    jax.lax.fori_loop(0, T, pipeline_step, 0)
-
-    z = z_ref[...]
-    gate = jax.nn.softmax(z[:, :m], axis=-1)
-    fit = jax.nn.sigmoid(z[:, m:])
-    p_ref[...] = jnp.sum(gate * fit, axis=-1, keepdims=True).astype(p_ref.dtype)
+def _pipelined_gather(ids, vals, table, *, m2: int, skip_id: int,
+                      planes: int, block_n: int, block_k: int,
+                      interpret: bool):
+    """Pad (N, K) to block multiples with pad-id slots, run the kernel over
+    ``table`` (a ``lane_rows`` / ``int8_plane_rows`` layout), slice back."""
+    N, K = ids.shape
+    block_n = max(1, min(block_n, N))
+    block_k = max(1, min(block_k, K))
+    n_pad = pl.cdiv(N, block_n) * block_n
+    k_pad = pl.cdiv(K, block_k) * block_k
+    ids = jnp.pad(ids, ((0, n_pad - N), (0, k_pad - K)),
+                  constant_values=skip_id)
+    vals = jnp.pad(vals.astype(jnp.float32), ((0, n_pad - N), (0, k_pad - K)))
+    tile = block_n * k_pad
+    vma = varying_axes(ids, vals, table)
+    smem_block = pl.BlockSpec((tile,), lambda i: (i,),
+                              memory_space=pltpu.SMEM)
+    p, z = pl.pallas_call(
+        functools.partial(_kernel, m=m2 // 2, block_n=block_n,
+                          block_k=block_k, nkb=k_pad // block_k,
+                          skip_id=skip_id, planes=planes),
+        grid=(n_pad // block_n,),
+        in_specs=[smem_block, smem_block,
+                  pl.BlockSpec(memory_space=pl.ANY)],  # table stays in HBM
+        out_specs=[
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, m2), lambda i: (i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n_pad, m2), jnp.float32, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_n, table.shape[1]), jnp.float32),
+            pltpu.VMEM((2, block_k, table.shape[1]), table.dtype),
+            pltpu.SemaphoreType.DMA((2, block_k)),
+        ],
+        interpret=interpret,
+    )(ids.reshape(-1), vals.reshape(-1), table)
+    return p[:N, 0], z[:N]
 
 
 @functools.partial(jax.jit,
@@ -253,17 +236,13 @@ def lsplm_sparse_fused_int8_forward(
     """Int8-native pipelined fused sparse forward: serve a quantised
     model WITHOUT materialising fp32 rows. Returns (p (N,), z (N, 2m)).
 
-    Identical gather/contraction structure to
-    :func:`lsplm_sparse_fused_forward` on the dequantised rows — the
-    row values entering each ``jnp.dot`` are the same fp32 numbers
-    (``codes * scale``), computed in the VMEM epilogue instead of
-    up-front in HBM, so the scores match the dequantise-then-score path
-    while the per-row DMA traffic drops ~4x (int8 codes + one fp32
-    scalar vs a fp32 row). Same VMEM/SMEM sizing rule as the fp32
-    kernel with the double buffers at 1/4 size; (block_n, block_k)
-    resolve from the autotune table under kernel key
-    ``"fused_fwd_int8"``. CI validates in interpret mode (see module
-    docstring).
+    Same gather/contraction pipeline as :func:`lsplm_sparse_fused_forward`,
+    over the four-rows-per-lane-row ``int8_plane_rows`` table: each row
+    DMA moves a quarter of the fp32 table's bytes per row, and each
+    slot's value carries its row's scale (``vals * scales[ids]``), so the
+    contraction sums ``(v * s) * code`` — the dequantise-then-score
+    numbers up to fp32 rounding. (block_n, block_k) resolve from the
+    autotune table under kernel key ``"fused_fwd_int8"``.
     """
     if ids.shape != vals.shape or ids.ndim != 2:
         raise ValueError(f"ids/vals must be (N, K), got {ids.shape}/{vals.shape}")
@@ -274,55 +253,11 @@ def lsplm_sparse_fused_int8_forward(
     if scales.shape != (codes.shape[0],):
         raise ValueError(
             f"scales must be ({codes.shape[0]},), got {scales.shape}")
-    N, K = ids.shape
     D, m2 = codes.shape
-    m = m2 // 2
-    block_n = max(1, min(block_n, N))
-    block_k = max(1, min(block_k, K))
-    n_pad = pl.cdiv(N, block_n) * block_n
-    k_pad = pl.cdiv(K, block_k) * block_k
-    if n_pad != N:
-        ids = jnp.concatenate(
-            [ids, jnp.full((n_pad - N, K), D - 1, ids.dtype)], axis=0)
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((n_pad - N, K), vals.dtype)], axis=0)
-    if k_pad != K:
-        ids = jnp.concatenate(
-            [ids, jnp.full((n_pad, k_pad - K), D - 1, ids.dtype)], axis=1)
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((n_pad, k_pad - K), vals.dtype)], axis=1)
-    nkb = k_pad // block_k
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, k_pad), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # codes stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # scales stay in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, *_: (i, 0)),
-            pl.BlockSpec((block_n, m2), lambda i, *_: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, block_k, m2), jnp.int8),
-            pltpu.VMEM((2, block_k, 1), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, block_k)),
-            pltpu.SemaphoreType.DMA((2, block_k)),
-        ],
-    )
-    p, z = pl.pallas_call(
-        functools.partial(_kernel_int8, m=m, block_n=block_n,
-                          block_k=block_k, nkb=nkb, skip_id=D - 1),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, m2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ids, vals, codes, scales.astype(jnp.float32).reshape(D, 1))
-    return p[:N, 0], z[:N]
+    vals = vals.astype(jnp.float32) * jnp.take(scales.astype(jnp.float32), ids)
+    return _pipelined_gather(ids, vals, int8_plane_rows(codes), m2=m2,
+                             skip_id=D - 1, planes=4, block_n=block_n,
+                             block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -346,49 +281,8 @@ def lsplm_sparse_fused_forward(
         raise ValueError(f"ids/vals must be (N, K), got {ids.shape}/{vals.shape}")
     if theta.ndim != 2 or theta.shape[1] % 2:
         raise ValueError(f"theta must be (D, 2m), got {theta.shape}")
-    N, K = ids.shape
     D, m2 = theta.shape
-    m = m2 // 2
-    block_n = max(1, min(block_n, N))
-    block_k = max(1, min(block_k, K))
-    n_pad = pl.cdiv(N, block_n) * block_n
-    k_pad = pl.cdiv(K, block_k) * block_k
-    if n_pad != N:
-        ids = jnp.concatenate(
-            [ids, jnp.full((n_pad - N, K), D - 1, ids.dtype)], axis=0)
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((n_pad - N, K), vals.dtype)], axis=0)
-    if k_pad != K:
-        ids = jnp.concatenate(
-            [ids, jnp.full((n_pad, k_pad - K), D - 1, ids.dtype)], axis=1)
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((n_pad, k_pad - K), vals.dtype)], axis=1)
-    nkb = k_pad // block_k
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, k_pad), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # Theta stays in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, *_: (i, 0)),
-            pl.BlockSpec((block_n, m2), lambda i, *_: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, block_k, m2), theta.dtype),
-            pltpu.SemaphoreType.DMA((2, block_k)),
-        ],
-    )
-    p, z = pl.pallas_call(
-        functools.partial(_kernel, m=m, block_n=block_n, block_k=block_k,
-                          nkb=nkb, skip_id=D - 1),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1), theta.dtype),
-            jax.ShapeDtypeStruct((n_pad, m2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ids, vals, theta)
-    return p[:N, 0], z[:N]
+    p, z = _pipelined_gather(ids, vals, lane_rows(theta.astype(jnp.float32)),
+                             m2=m2, skip_id=D - 1, planes=1, block_n=block_n,
+                             block_k=block_k, interpret=interpret)
+    return p.astype(theta.dtype), z

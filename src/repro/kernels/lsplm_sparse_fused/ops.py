@@ -16,8 +16,9 @@ oracle materialises, which is what makes it win at production sparsity
 Plus the INFERENCE-ONLY int8-native pair — ``sparse_gather_matmul_int8``
 and ``lsplm_sparse_forward_int8`` — which score a quantised model
 (int8 ``codes`` + per-row fp32 ``scales``) without ever materialising
-fp32 rows: the kernel DMAs int8 code rows and applies the scale in the
-VMEM epilogue (~4x fewer row-DMA bytes), the jnp fallback fuses the same
+fp32 rows: the kernel gathers from int8 codes packed four rows per
+lane-aligned row (a quarter of the fp32 table's bytes) with each row's
+scale folded into its slot value, the jnp fallback fuses the scale
 multiply into its gather chunks. No VJP: training stays fp32,
 quantisation is a deploy-time transform (``repro.serve.compress``).
 
@@ -462,10 +463,10 @@ def sparse_gather_matmul_int8(ids, vals, codes, scales, *, mode: str = "auto",
 
     ``codes`` is the (D, 2m) int8 matrix with the zero pad row at D-1;
     ``scales`` the (D,) per-row fp32 scales (pad row scale 0). On the
-    kernel path the row DMAs move int8 + one fp32 scalar per row (~4x
-    fewer bytes than fp32 rows at production K << d) and the scale is
-    applied in the VMEM epilogue; the jnp fallback fuses the same
-    multiply into its gather chunks. INFERENCE-ONLY: no custom VJP —
+    kernel path the row DMAs move packed int8 codes (a quarter of the
+    fp32 table's bytes per row) and each slot's value carries its row's
+    scale; the jnp fallback fuses the scale multiply into its gather
+    chunks. INFERENCE-ONLY: no custom VJP —
     training differentiates the fp32 ops, quantisation is a deploy-time
     transform. Knobs resolve from the autotune table under
     ``"fused_fwd_int8"``.

@@ -24,18 +24,22 @@ in plan order plus a trailing zero row — and the caller densifies it
 with the plan's ``inv_compact`` gather. That keeps the kernel free of
 (D, 2m) traffic entirely: HBM cost is O(U) writes, not O(D).
 
-Scalar-prefetched operands (``row_ids``, ``sample_sorted``) live in SMEM
-so the flush target and the dz row index are known without touching
-VMEM. dz rides in VMEM whole: (N, 2m) fp32 is ~3 MB at N=32k, m=12 —
-well under budget; for larger batches slice the batch before planning.
+The sorted entries (``row_ids``, ``sample_sorted``, ``vals_sorted``) arrive
+as 1-D SMEM blocks, so the run id, the dz row index and the entry value
+are scalars. dz is copied once into a VMEM scratch, each row right-padded
+to whole 128-lane tiles (the accumulator, the flush DMAs and the compact
+output use the same lane-aligned rows — the DMA engine cannot slice a
+2m-wide row). dz costs N * 512 B of VMEM at 2m <= 128: 8 MiB at N=16k;
+the scoped-VMEM limit is raised to fit it, and batches past
+``MAX_DZ_VMEM_BYTES`` must be sliced before planning.
 
 The plan pads the sorted entries with at least one trailing sentinel
 (id == num_rows, never a real id): the sentinel both triggers the final
 flush of the last real run and absorbs the tail of the last grid block.
 
-CI exercises this kernel in interpret mode; the compiled Mosaic path
-follows the same sequential-grid contract (see the package README note
-in ops.py).
+The CPU test suite runs this kernel in interpret mode;
+``tests/test_tpu_compile.py`` compiles it for a described TPU v5e, and
+``chip_smoke.py`` runs it on the chip.
 """
 from __future__ import annotations
 
@@ -46,9 +50,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lsplm_sparse_fused.lsplm_sparse_fused import (
+    lane_rows,
+    varying_axes,
+)
 
-def _kernel(row_ids_ref, sample_ref, vals_ref, dz_ref, out_ref,
-            acc, cursor, sems, *, block_e: int, num_kept: int, total: int):
+MAX_DZ_VMEM_BYTES = 64 << 20
+
+
+def _kernel(row_ids_ref, sample_ref, vals_ref, dz_hbm, out_ref,
+            dz, acc, cursor, sems, dz_sem, *, block_e: int, num_kept: int,
+            total: int):
     # SMEM cursor layout (persists across sequential grid steps):
     #   [0] id of the current run          [1] next compact row to write
     #   [2] active accumulator slot        [3+s] slot s copy in flight?
@@ -57,45 +69,47 @@ def _kernel(row_ids_ref, sample_ref, vals_ref, dz_ref, out_ref,
 
     @pl.when(pid == 0)
     def _init():
+        copy = pltpu.make_async_copy(dz_hbm, dz, dz_sem)
+        copy.start()
         acc[...] = jnp.zeros_like(acc)
         cursor[0] = row_ids_ref[0]   # id of the first run
         for i in range(1, 7):
             cursor[i] = 0
+        copy.wait()
+
+    def flush_copy(slot, row):
+        return pltpu.make_async_copy(acc.at[slot], out_ref.at[pl.ds(row, 1)],
+                                     sems.at[slot])
 
     def drain(slot):
         # deferred wait: rebuild slot's outstanding copy descriptor from
         # the tracked destination row and settle its semaphore
         @pl.when(cursor[3 + slot] == 1)
         def _():
-            pltpu.make_async_copy(
-                acc.at[slot], out_ref.at[cursor[5 + slot]],
-                sems.at[slot]).wait()
+            flush_copy(slot, cursor[5 + slot]).wait()
             cursor[3 + slot] = 0
 
     def entry(e, carry):
         gid = pid * block_e + e
-        rid = row_ids_ref[gid]
+        rid = row_ids_ref[e]
 
         @pl.when(rid != cursor[0])
         def _flush():
             slot = cursor[2]
             other = 1 - slot
             drain(other)  # the slot we are about to accumulate into
-            copy = pltpu.make_async_copy(
-                acc.at[slot], out_ref.at[cursor[1]], sems.at[slot])
-            copy.start()  # overlaps the next run's accumulation below
+            flush_copy(slot, cursor[1]).start()  # overlaps the next run
             cursor[3 + slot] = 1
             cursor[5 + slot] = cursor[1]
-            acc[other, :] = jnp.zeros_like(acc[other, :])
+            acc[other] = jnp.zeros(acc.shape[1:], jnp.float32)
             cursor[0] = rid
             cursor[1] = cursor[1] + 1
             cursor[2] = other
 
         @pl.when(gid < num_kept)
         def _accumulate():
-            n = sample_ref[gid]
             s = cursor[2]
-            acc[s, :] = acc[s, :] + vals_ref[e].astype(jnp.float32) * dz_ref[n, :]
+            acc[s] = acc[s] + vals_ref[e] * dz[pl.ds(sample_ref[e], 1), :]
 
         # last entry overall: the sentinel tail flushed the final real run
         # above and accumulated nothing since, so the active slot is zero —
@@ -106,8 +120,7 @@ def _kernel(row_ids_ref, sample_ref, vals_ref, dz_ref, out_ref,
         def _zero_row():
             slot = cursor[2]
             drain(1 - slot)
-            copy = pltpu.make_async_copy(
-                acc.at[slot], out_ref.at[cursor[1]], sems.at[slot])
+            copy = flush_copy(slot, cursor[1])
             copy.start()
             copy.wait()
 
@@ -138,26 +151,35 @@ def lsplm_sparse_scatter_compact(
     E_pad = row_ids.shape[0]
     if E_pad % block_e:
         raise ValueError(f"E_pad={E_pad} not a multiple of block_e={block_e}")
-    N, m2 = dz.shape
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(E_pad // block_e,),
-        in_specs=[
-            pl.BlockSpec((block_e,), lambda i, *_: (i,)),
-            pl.BlockSpec((N, m2), lambda i, *_: (0, 0)),  # dz whole, VMEM
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, m2), jnp.float32),   # double-buffered accumulator
-            pltpu.SMEM((7,), jnp.int32),        # run/row/slot/in-flight cursor
-            pltpu.SemaphoreType.DMA((2,)),      # one per accumulator slot
-        ],
-    )
-    return pl.pallas_call(
+    m2 = dz.shape[1]
+    dz_rows = lane_rows(dz.astype(jnp.float32))
+    dz_bytes = dz_rows.size * 4
+    if dz_bytes > MAX_DZ_VMEM_BYTES:
+        raise ValueError(
+            f"dz needs {dz_bytes:,} B of VMEM (> {MAX_DZ_VMEM_BYTES:,}); "
+            f"slice the batch before planning")
+    smem_block = pl.BlockSpec((block_e,), lambda i: (i,),
+                              memory_space=pltpu.SMEM)
+    compact = pl.pallas_call(
         functools.partial(_kernel, block_e=block_e, num_kept=num_kept,
                           total=E_pad),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_unique + 1, m2), jnp.float32),
+        grid=(E_pad // block_e,),
+        in_specs=[smem_block, smem_block, smem_block,
+                  pl.BlockSpec(memory_space=pl.ANY)],  # dz: copied once
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_unique + 1, dz_rows.shape[1]), jnp.float32,
+            vma=varying_axes(row_ids, sample_sorted, vals_sorted, dz)),
+        scratch_shapes=[
+            pltpu.VMEM(dz_rows.shape, jnp.float32),  # dz, lane-aligned rows
+            pltpu.VMEM((2, 1, dz_rows.shape[1]), jnp.float32),  # accumulator
+            pltpu.SMEM((7,), jnp.int32),        # run/row/slot/in-flight cursor
+            pltpu.SemaphoreType.DMA((2,)),      # one per accumulator slot
+            pltpu.SemaphoreType.DMA(()),        # the one dz copy
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=dz_bytes + (16 << 20)),
         interpret=interpret,
-    )(row_ids, sample_sorted, vals_sorted, dz.astype(jnp.float32))
+    )(row_ids, sample_sorted, vals_sorted.astype(jnp.float32), dz_rows)
+    return compact[:, :m2]

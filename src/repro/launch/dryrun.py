@@ -148,8 +148,6 @@ def lower_combo(cfg, shape_name: str, mesh, serve_dtype=jnp.bfloat16,
 
 def _costs(compiled):
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4 returns one dict per device
-        ca = ca[0]
     coll = collective_bytes(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)),

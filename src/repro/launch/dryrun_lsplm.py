@@ -75,8 +75,6 @@ def run(mesh_name: str, variant: str = "baseline"):
     compiled = lowered.compile()
     dt = time.time() - t0
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4 returns one dict per device
-        ca = ca[0]
     ma = compiled.memory_analysis()
     coll = collective_bytes(compiled.as_text())
     # Algorithm 1's line search is a while loop (body counted once); its

@@ -18,10 +18,8 @@ MULTI_POD_SHAPE = (2, 16, 16)  # 2 pods x 256 chips
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -43,12 +43,14 @@ accepted request.
 """
 import argparse
 import time
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 from repro.io import checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.tuning import (
     add_tuning_flags,
     apply_tuning_flags,
@@ -104,7 +106,16 @@ def _trained_theta(args) -> jnp.ndarray:
     return theta
 
 
-def main() -> int:
+class ServeRun(NamedTuple):
+    """What :func:`serve` leaves behind: the warmed engine, the replayed
+    requests and the engine's per-request scores (single dispatches)."""
+
+    engine: ScoringEngine
+    requests: list
+    scores: list
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt", default=None,
                     help="training checkpoint with a 'theta' entry; "
@@ -143,7 +154,12 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     add_tuning_flags(ap)
     obs.add_flags(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> int:
+    args = build_parser().parse_args()
+    enable_compile_cache()
     apply_tuning_flags(args)  # value check up front; geometry check below
     if args.drift_ref and not args.monitor:
         raise SystemExit(
@@ -156,7 +172,8 @@ def main() -> int:
 
     session = obs.configure_from_args(args, driver="repro.launch.serve")
     try:
-        return _serve(args)
+        serve(args)
+        return 0
     finally:
         session.close()
 
@@ -193,8 +210,12 @@ def _real_clock_smoke(engine, requests, *, qps: float, config: QueueConfig,
             f"{fl['drain']} drain / {fl['coalesced']} coalesced)")
 
 
-def _serve(args) -> int:
-    theta = _trained_theta(args)
+def serve(args, theta=None) -> ServeRun:
+    """Prune ``theta`` (default: ``--ckpt`` or a fresh small training run),
+    optionally quantise it, warm an engine on the traffic's envelopes and
+    replay the requests, asserting zero steady-state recompiles."""
+    if theta is None:
+        theta = _trained_theta(args)
     d = theta.shape[0]
 
     art = compress(theta)
@@ -322,7 +343,7 @@ def _serve(args) -> int:
                 + (f"; drift signals: "
                    + ", ".join(f"{k}={v:.4f}" for k, v in sorted(drift.items()))
                    if drift else ""))
-    return 0
+    return ServeRun(engine, requests, single)
 
 
 if __name__ == "__main__":

@@ -38,6 +38,7 @@ if "REPRO_DEVICES" in os.environ:  # must precede jax import
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,7 @@ from repro.core.objective import smooth_loss_and_grad
 from repro.data import CTRDataConfig, auc, generate, pad_to_multiple, to_dense_batch
 from repro.dist import make_distributed_step, shard_batch, shard_state
 from repro.io import checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.tuning import (
     add_tuning_flags,
@@ -60,7 +62,18 @@ from repro.launch.tuning import (
 from repro.optim import OWLQNPlus
 
 
-def train_sparse(args) -> int:
+class SparseRun(NamedTuple):
+    """What :func:`train_sparse` leaves behind: the trained global Theta,
+    the per-iteration OWLQN+ stats (device values, fetched by the caller),
+    and the jitted step with its final state (for inspecting the program)."""
+
+    theta: jax.Array
+    stats: list
+    step: object
+    state: object
+
+
+def train_sparse(args) -> SparseRun:
     """Production-format training: padded-COO ids/vals over d columns,
     OWLQN+ on the fused sparse kernel's custom-VJP loss. Dense (B, d)
     matrices never exist; the backward touches only active Theta rows,
@@ -140,10 +153,12 @@ def train_sparse(args) -> int:
         step = jax.jit(opt.step)
 
     tracer = obs.get_tracer()
+    history = []
     for k in range(args.iters):
         t0 = time.perf_counter()
         with tracer.step_span("train/iter", k):
             state, stats = step(state)
+        history.append(stats)
         dt = time.perf_counter() - t0
         if k % 5 == 0 or k == args.iters - 1:
             theta_eval = state.theta if part is None else part.unpad_rows(
@@ -170,17 +185,17 @@ def train_sparse(args) -> int:
     if args.ckpt:
         checkpoint.save(args.ckpt, {"theta": theta})
         obs.log(f"checkpoint -> {args.ckpt}")
-    return 0
+    return SparseRun(theta, history, step, state)
 
 
-def train_stream(args) -> int:
+def train_stream(args) -> list:
     """Day-by-day streaming training (repro.stream): per day, the last
     --window days are re-planned on the host — overlapped with the
     previous window's device iterations — and OWLQN+ runs --inner-iters
     warm-started steps. --mesh-data/--mesh-model runs every window on
     the sharded path (fixed equal id-range partition). --ckpt saves the
     resumable stream state (Theta + history + day cursor); --resume
-    continues from it."""
+    continues from it. Returns the trainer's per-window stats."""
     from repro.core.objective import nll_sparse
     from repro.data import auc as auc_fn
     from repro.data.sparse import sparse_predict
@@ -262,7 +277,7 @@ def train_stream(args) -> int:
 
     t0 = time.perf_counter()
     days_left = stream.num_days - state.day
-    state, _trace = trainer.run(state, callback=cb)
+    state, trace = trainer.run(state, callback=cb)
     dt = time.perf_counter() - t0
     ps = trainer.planner_stats
     obs.log(f"trained {days_left} windows in {dt:.1f}s; planner: "
@@ -282,7 +297,7 @@ def train_stream(args) -> int:
                 f"{obs.save_drift_reference(args.drift_ref, ref)}")
     if ckpt:
         obs.log(f"stream checkpoint -> {ckpt} (resume with --resume)")
-    return 0
+    return trace
 
 
 def train_dense(args) -> int:
@@ -349,7 +364,7 @@ def train_dense(args) -> int:
     return 0
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=4000)
     ap.add_argument("--user-features", type=int, default=64)
@@ -390,8 +405,12 @@ def main():
                     help="--stream: resume from --ckpt if it exists")
     add_tuning_flags(ap)
     obs.add_flags(ap)
-    args = ap.parse_args()
+    return ap
 
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
     if tuning_flags_set(args) and not (args.sparse or args.stream):
         raise SystemExit(
             "--block-n/--block-k/--chunk/--tune steer the sparse kernels; "
@@ -407,10 +426,12 @@ def main():
                                       mode=mode)
     try:
         if args.stream:
-            return train_stream(args)
-        if args.sparse:
-            return train_sparse(args)
-        return train_dense(args)
+            train_stream(args)
+        elif args.sparse:
+            train_sparse(args)
+        else:
+            train_dense(args)
+        return 0
     finally:
         session.close()
 

@@ -149,8 +149,6 @@ def moe_ffn(
         )
         return out.reshape(B, S, d), _aux_loss(probs, idx, E)
 
-    from jax.experimental.shard_map import shard_map
-
     model_size = mesh.shape["model"]
     assert E % model_size == 0, (E, model_size)
     E_loc = E // model_size
@@ -182,13 +180,13 @@ def moe_ffn(
             aux = _aux_loss(probs, idx, E)
             return out.reshape(xl.shape), aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             local_tg,
             mesh=mesh,
             in_specs=(P(dp, None, None), P(), P("model", None, dp),
                       P("model", None, dp), P("model", dp, None)),
             out_specs=(P(dp, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, params["router"], params["w1"], params["w3"], params["w2"])
         return out, aux
 
@@ -210,13 +208,13 @@ def moe_ffn(
         aux = jax.lax.pmean(aux, dp) if dp else aux
         return out.reshape(xl.shape), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(dp, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w1"], params["w3"], params["w2"])
     return out, aux
 

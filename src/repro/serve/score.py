@@ -13,12 +13,12 @@ model argument is polymorphic:
   * an int8 :class:`~repro.serve.compress.QuantizedArtifact` — served
     INT8-NATIVE: the codes/scales are kept as-is and the sparse paths
     run the int8 gather ops (``lsplm_sparse_forward_int8`` /
-    ``sparse_gather_matmul_int8``), which DMA int8 code rows and apply
-    the per-row fp32 scale in the gather epilogue — fp32 rows are never
-    materialised, the row gather moves ~4x fewer bytes, and the scores
-    are the dequantise-then-score numbers exactly (same fp32 row values
-    enter the same contraction; bounded-error vs the unquantised fp32
-    model, see ``serve.compress``). The one exception is the DENSE path,
+    ``sparse_gather_matmul_int8``), which gather packed int8 code rows
+    and fold the per-row fp32 scale into each slot's value — fp32 rows
+    are never materialised, the row gather moves ~4x fewer bytes, and
+    the scores are the dequantise-then-score numbers up to fp32 rounding
+    (bounded-error vs the unquantised fp32 model, see
+    ``serve.compress``). The one exception is the DENSE path,
     which has no gather to fuse into: it dequantises on the fly (a
     (R, 2m) multiply per call — fine off the hot path, wasteful on it).
 

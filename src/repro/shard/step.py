@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sparse_batch_specs
@@ -85,6 +84,11 @@ def sharded_sparse_nll(theta: jax.Array, sbatch: ShardedSparseBatch,
         it = iter(plans)
         u_plan = cell_plan(next(it)) if has_user_plan else None
         a_plan = cell_plan(next(it)) if has_ad_plan else None
+        # theta_l varies over 'model' only; the routed batch also varies
+        # over the data axes. Cast Theta varying there too, so its
+        # cotangent (a per-data-block scatter) types-checks; the
+        # transpose of this cast is the data-axis psum of dTheta.
+        theta_l = jax.lax.pcast(theta_l, reduce_axes, to="varying")
         tp = pad_theta(theta_l)  # local zero pad row at index R
         z_u = sparse_gather_matmul(u_ids[0], u_vals[0], tp, mode=mode,
                                    plan=u_plan)
@@ -108,8 +112,8 @@ def sharded_sparse_nll(theta: jax.Array, sbatch: ShardedSparseBatch,
     if has_ad_plan:
         args.append(sbatch.ad_plan)
         in_specs.append(specs.ad_plan)
-    return shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P())(theta, *args)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P())(theta, *args)
 
 
 def sharded_sparse_loss_and_grad(theta: jax.Array,

@@ -37,8 +37,6 @@ for arch in archs:
     for shape in shapes:
         lowered, compiled, meta = dryrun.lower_combo(cfg, shape, mesh)
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax<=0.4: one dict per device
-            ca = ca[0]
         assert ca.get("flops", 0) > 0, (arch, shape)
         txt = compiled.as_text()
         coll = collective_bytes(txt)
