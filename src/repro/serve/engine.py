@@ -40,9 +40,14 @@ construction; requests stay in the original id space either way.
 :class:`EngineStats` keeps the latency/throughput ledger: request and
 candidate counts, dispatch (AOT call) and padded-slot counts with the
 implied batch occupancy, per-envelope hit counts, compile count and
-seconds, scoring wall seconds, and the observed request rate (QPS) over
-the scoring span (used by ``benchmarks/bench_serve.py`` and the
-``repro.launch.serve`` smoke).
+seconds, and scoring wall seconds (used by ``benchmarks/bench_serve.py``
+and the ``repro.launch.serve`` smoke).
+
+With a tracer on (``repro.obs``), each dispatch is one ``serve/dispatch``
+span with four children in order: ``serve/pad`` (the padded numpy
+batch), ``serve/launch`` (the executable call: argument transfer and
+enqueue), ``serve/sync`` (``block_until_ready``) and ``serve/readback``
+(the scores' copy to the host, reshaped and sliced per request).
 """
 from __future__ import annotations
 
@@ -99,8 +104,6 @@ class EngineStats:
         self._wall_hist = reg.histogram("serve_dispatch_wall_seconds",
                                         **labels)
         self._hits: dict[tuple, obs.Counter] = {}
-        self._first_t: float | None = None
-        self._last_t: float | None = None
 
     # ------------------------------------------------------------- mutators
     def note_compile(self, seconds: float) -> None:
@@ -113,7 +116,6 @@ class EngineStats:
         requests/candidates it carried, and its wall time."""
         self._score_s.inc(wall_s)
         self._wall_hist.observe(wall_s)
-        self.note_span()
         self._dispatches.inc(1.0)
         self._slots.inc(float(key[0]))
         self._requests.inc(float(requests))
@@ -125,13 +127,6 @@ class EngineStats:
                                     **self._labels)
             self._hits[key] = hit
         hit.inc(float(requests))
-
-    def note_span(self) -> None:
-        """Stamp the scoring span (first/last dispatch) for QPS."""
-        now = time.perf_counter()
-        if self._first_t is None:
-            self._first_t = now
-        self._last_t = now
 
     # ---------------------------------------------------------------- views
     @property
@@ -181,14 +176,6 @@ class EngineStats:
         """Real requests per padded bundle slot (1.0 = no G padding)."""
         return self.requests / self.slots if self.slots else 0.0
 
-    @property
-    def qps(self) -> float:
-        """Observed request rate over the scoring span (first to last
-        dispatch); 0 until two dispatches have landed."""
-        if self._first_t is None or self._last_t == self._first_t:
-            return 0.0
-        return self.requests / (self._last_t - self._first_t)
-
     def as_dict(self) -> dict:
         return {
             "requests": self.requests,
@@ -196,7 +183,6 @@ class EngineStats:
             "dispatches": self.dispatches,
             "slots": self.slots,
             "occupancy": self.occupancy,
-            "qps": self.qps,
             "compiles": self.compiles,
             "compile_seconds": self.compile_seconds,
             "score_seconds": self.score_seconds,
@@ -334,12 +320,21 @@ class ScoringEngine:
         key = (_round_up(len(requests), self._g_buckets), ku, ka, n,
                self._dtype)
         comp = self._executable(key)  # compile time books separately
+        tracer = obs.get_tracer()
+        args = ({"g": key[0], "envelope": "x".join(map(str, key))}
+                if tracer.enabled else {})
         t0 = time.perf_counter()
-        with obs.get_tracer().span("serve/dispatch", g=key[0],
-                                   envelope="x".join(map(str, key))):
-            ui, uv, ai, av = self._pad_batch(requests, key)
-            p = np.asarray(jax.block_until_ready(comp(ui, uv, ai, av)))
-            p = p.reshape(key[0], n)
+        with tracer.span("serve/dispatch", **args):
+            with tracer.span("serve/pad"):
+                ui, uv, ai, av = self._pad_batch(requests, key)
+            with tracer.span("serve/launch"):  # argument transfer, enqueue
+                p = comp(ui, uv, ai, av)
+            with tracer.span("serve/sync"):
+                p = jax.block_until_ready(p)
+            with tracer.span("serve/readback"):
+                p = np.asarray(p).reshape(key[0], n)
+                out = [p[s, :r.ad_ids.shape[0]]
+                       for s, r in enumerate(requests)]
         wall = time.perf_counter() - t0
         n_cands = sum(r.ad_ids.shape[0] for r in requests)
         self.stats.note_dispatch(key, len(requests), n_cands, wall)
@@ -351,7 +346,6 @@ class ScoringEngine:
                 requests=len(requests), candidates=n_cands,
                 occupancy=len(requests) / key[0], wall_s=wall,
                 flush_reason=reason, queue_delay_us=qdelay)
-        out = [p[s, :r.ad_ids.shape[0]] for s, r in enumerate(requests)]
         mon = obs.get_monitor()
         if mon.enabled:
             mon.observe_dispatch(out, requests)

@@ -49,7 +49,16 @@ engine dispatch and the measured wall time advances the (single,
 serial) server: flush start = max(trigger time, server free), and every
 request in the batch completes when its dispatch finishes. A batch is
 sealed at its trigger; arrivals while the server is busy join the next
-one.
+one. Under :class:`RealClockPump` the timeline is measured instead: a
+flush's start and end are the pump clock's readings when the flush
+begins executing and when ``score_batch`` returns, so the queueing
+delay includes any wait for the lock. Which requests flush together,
+and when, is the same in both modes.
+
+With a tracer on (``repro.obs``), ``serve/admit`` covers a pump submit
+from before the lock is taken to its release, and ``serve/flush``
+(args ``reason``, ``size``) covers each flush, around the engine's
+``serve/dispatch`` spans.
 
 :func:`replay_open_loop` is the benchmark harness: OPEN-LOOP arrivals
 (Poisson with rate ``qps``, drawn up front, independent of completions
@@ -90,9 +99,9 @@ class Completion(NamedTuple):
 
     ticket: int
     scores: np.ndarray  # (N_real,) p(y=1|x), request order
-    arrival: float  # virtual seconds
+    arrival: float  # queue clock seconds
     started: float  # flush execution start (>= arrival)
-    completed: float  # started + measured dispatch wall time
+    completed: float  # scores back: measured, or started + dispatch wall
     reason: str  # "full" | "deadline" | "drain" | "coalesced"
 
     @property
@@ -182,7 +191,8 @@ class MicroBatchQueue:
     """Deadline-aware micro-batching front of a :class:`ScoringEngine`.
 
     Single-threaded and virtual-clocked: callers push time forward via
-    the ``now`` arguments (monotonic seconds, non-decreasing). Completed
+    the ``now`` arguments (monotonic seconds, non-decreasing); a flush's
+    start and end are modelled unless :attr:`clock` is set. Completed
     work accumulates in :attr:`completions` (also returned by the call
     that produced it).
     """
@@ -199,6 +209,9 @@ class MicroBatchQueue:
                             list[tuple[int, BundleRequest, float]]] = {}
         self._next_ticket = 0
         self._busy_until = 0.0  # virtual time the serial server frees up
+        # the clock a flush stamps its start and end on; None keeps the
+        # modelled virtual timeline (RealClockPump sets its own clock)
+        self.clock = None
 
     @property
     def pending(self) -> int:
@@ -270,24 +283,10 @@ class MicroBatchQueue:
     # ------------------------------------------------------------ internals
     def _flush(self, env: tuple[int, int, int], trigger: float,
                reason: str) -> list[Completion]:
-        entries = self._pending.pop(env)
-        started = max(trigger, self._busy_until)
-        # virtual queueing delay of the OLDEST request in the batch —
-        # the figure the deadline bounds
-        queue_delay_s = max(0.0, started - entries[0][2])
-        self.stats.note_flush(reason, queue_delay_s, size=len(entries))
-        self.stats.note_pending(self.pending)
-        before = self.engine.stats.score_seconds
-        with self.engine.dispatch_context(reason, queue_delay_s * 1e6):
-            scores = self.engine.score_batch([r for _, r, _ in entries])
-        wall = self.engine.stats.score_seconds - before
-        completed = started + wall
-        self._busy_until = completed
-        out = [Completion(ticket=t, scores=p, arrival=arr, started=started,
-                          completed=completed, reason=reason)
-               for (t, _, arr), p in zip(entries, scores)]
-        self.completions += out
-        return out
+        with obs.get_tracer().span("serve/flush", reason=reason,
+                                   size=len(self._pending[env])):
+            return self._serve(self._pending.pop(env), trigger, reason,
+                               self.engine.score_batch)
 
     def _flush_coalesced(self, envs: Sequence[tuple[int, int, int]],
                          trigger: float) -> list[Completion]:
@@ -296,23 +295,42 @@ class MicroBatchQueue:
         envelope of the members, then completions slice back per ticket.
         Widening only adds pad slots (zero pad row), so the scores are
         bitwise what per-envelope dispatch would return."""
-        widest = tuple(max(e[i] for e in envs) for i in range(3))
-        entries = sorted((t for env in envs for t in self._pending.pop(env)),
-                         key=lambda e: e[0])
-        started = max(trigger, self._busy_until)
+        size = sum(len(self._pending[env]) for env in envs)
+        with obs.get_tracer().span("serve/flush", reason="coalesced",
+                                   size=size):
+            widest = tuple(max(e[i] for e in envs) for i in range(3))
+            entries = sorted(
+                (t for env in envs for t in self._pending.pop(env)),
+                key=lambda e: e[0])
+            return self._serve(
+                entries, trigger, "coalesced",
+                lambda reqs: self.engine.score_batch_at(reqs, widest),
+                groups=len(envs))
+
+    def _serve(self, entries, trigger: float, reason: str, score,
+               groups: int = 1) -> list[Completion]:
+        """Score one sealed batch and stamp its timeline: measured on
+        :attr:`clock` when one is set, else modelled on the virtual
+        clock (start = max(trigger, server free), end = start + the
+        engine's measured wall)."""
+        started = (self.clock() if self.clock is not None
+                   else max(trigger, self._busy_until))
+        # queueing delay of the OLDEST request in the batch — the figure
+        # the deadline bounds
         queue_delay_s = max(0.0, started - min(arr for _, _, arr in entries))
-        self.stats.note_flush("coalesced", queue_delay_s,
-                              size=len(entries), groups=len(envs))
+        self.stats.note_flush(reason, queue_delay_s, size=len(entries),
+                              groups=groups)
         self.stats.note_pending(self.pending)
         before = self.engine.stats.score_seconds
-        with self.engine.dispatch_context("coalesced", queue_delay_s * 1e6):
-            scores = self.engine.score_batch_at(
-                [r for _, r, _ in entries], widest)
-        wall = self.engine.stats.score_seconds - before
-        completed = started + wall
+        with self.engine.dispatch_context(reason, queue_delay_s * 1e6):
+            scores = score([r for _, r, _ in entries])
+        if self.clock is not None:
+            completed = self.clock()
+        else:
+            completed = started + (self.engine.stats.score_seconds - before)
         self._busy_until = completed
         out = [Completion(ticket=t, scores=p, arrival=arr, started=started,
-                          completed=completed, reason="coalesced")
+                          completed=completed, reason=reason)
                for (t, _, arr), p in zip(entries, scores)]
         self.completions += out
         return out
@@ -392,12 +410,14 @@ class RealClockPump:
     accepted request has a completion and no timer is live.
 
     ``clock`` is injectable (default ``time.perf_counter``) so tests can
-    drive the pump on a synthetic clock.
+    drive the pump on a synthetic clock. The pump hands it to the queue,
+    whose flushes then stamp their start and end on it.
     """
 
     def __init__(self, queue: MicroBatchQueue, *, clock=time.perf_counter):
         self.queue = queue
         self.clock = clock
+        queue.clock = clock  # flushes stamp measured starts and ends
         self._cond = threading.Condition()
         self._stop = False
         self._thread: threading.Thread | None = None
@@ -431,11 +451,14 @@ class RealClockPump:
 
     # -------------------------------------------------------------- serving
     def submit(self, request: BundleRequest) -> int | None:
-        """Enqueue at wall time; returns the ticket (None if shed)."""
-        with self._cond:
-            ticket = self.queue.submit(request, self.clock())
-            self._cond.notify_all()  # re-arm the timer for the new deadline
-            return ticket
+        """Enqueue at wall time; returns the ticket (None if shed). The
+        ``serve/admit`` span covers the wait for the lock and any full
+        flush run inline."""
+        with obs.get_tracer().span("serve/admit"):
+            with self._cond:
+                ticket = self.queue.submit(request, self.clock())
+                self._cond.notify_all()  # re-arm the timer for the new deadline
+        return ticket
 
     def completions(self) -> list[Completion]:
         with self._cond:

@@ -205,6 +205,43 @@ def test_disabled_tracer_records_nothing():
     assert tr.span("a") is tr.step_span("b", 1)  # one shared null span
 
 
+def test_disabled_tracer_records_no_serve_span_and_builds_no_dispatch_args():
+    """The serving path under a disabled tracer: every span is asked for
+    and none recorded, and the dispatch's envelope label is not built."""
+    from repro.serve import (MicroBatchQueue, QueueConfig, RealClockPump,
+                             ScoringEngine, synthetic_requests)
+
+    class Asked(Tracer):
+        def __init__(self):
+            super().__init__(enabled=False)
+            self.asked = []
+
+        def span(self, name, **args):
+            self.asked.append((name, args))
+            return super().span(name, **args)
+
+    rng = np.random.default_rng(0)
+    theta = jnp.asarray(rng.normal(size=(300, 6)).astype(np.float32) * 0.3)
+    reqs = synthetic_requests(2, num_features=300, seed=1,
+                              k_user=(4, 4), k_ad=(2, 2), n_ads=(3, 3))
+    eng = ScoringEngine(theta)
+    eng.warm({eng.envelope(reqs[0])}, batch_sizes=eng.g_buckets)
+    tracer = Asked()
+    prev = obs.set_tracer(tracer)
+    try:
+        pump = RealClockPump(MicroBatchQueue(eng, QueueConfig(max_batch=2)))
+        for r in reqs:
+            pump.submit(r)
+        pump.stop()
+    finally:
+        obs.set_tracer(prev)
+    assert tracer.events() == []
+    assert [n for n, _ in tracer.asked] == [
+        "serve/admit", "serve/admit", "serve/flush", "serve/dispatch",
+        "serve/pad", "serve/launch", "serve/sync", "serve/readback"]
+    assert dict(tracer.asked)["serve/dispatch"] == {}
+
+
 # --------------------------------------------------------------- ledger
 def test_ledger_round_trip_and_offline_validation(tmp_path):
     path = str(tmp_path / "run.jsonl")

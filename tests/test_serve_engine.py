@@ -1,10 +1,12 @@
 """The bucketed scoring engine: envelope rounding, padded-score parity
 with direct unpadded scoring, the steady-state ZERO-recompile guarantee
-under a randomized request replay, and the stats ledger."""
+under a randomized request replay, the stats ledger, and the spans of a
+dispatch."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.serve import (
     BundleRequest,
     ScoreBundle,
@@ -125,6 +127,40 @@ def test_stats_ledger(theta):
     d = s.as_dict()
     assert d["requests"] == 10 and len(d["bucket_hits"]) == len(s.bucket_hits)
     assert d["occupancy"] == 1.0 and d["dispatches"] == 10
+
+
+def test_dispatch_span_has_four_children_in_order(theta):
+    """One score_batch dispatch records ``serve/dispatch`` with exactly
+    pad, launch, sync and readback nested inside it, in that order, on
+    the calling thread."""
+    reqs = synthetic_requests(3, num_features=D, k_user=(5, 5), k_ad=(4, 4),
+                              n_ads=(3, 3), seed=12)
+    eng = ScoringEngine(theta)
+    eng.warm({eng.envelope(reqs[0])}, batch_sizes=eng.g_buckets)
+    tracer = obs.Tracer(enabled=True)
+    prev = obs.set_tracer(tracer)
+    try:
+        got = eng.score_batch(reqs)
+    finally:
+        obs.set_tracer(prev)
+    spans = sorted((e for e in tracer.events() if e["ph"] == "X"),
+                   key=lambda e: e["ts"])
+    assert [e["name"] for e in spans] == [
+        "serve/dispatch", "serve/pad", "serve/launch", "serve/sync",
+        "serve/readback"]
+    outer, children = spans[0], spans[1:]
+    assert len({e["tid"] for e in spans}) == 1
+    assert outer["args"] == {"g": 4, "envelope": "x".join(
+        map(str, (4, *eng.envelope(reqs[0]), "fp32")))}
+    end = outer["ts"]
+    for c in children:
+        assert c["ts"] >= end - 1e-6  # in order, none overlapping the last
+        end = c["ts"] + c["dur"]
+        assert "args" not in c
+    assert end <= outer["ts"] + outer["dur"] + 1e-6
+    want = ScoringEngine(theta).score_batch(reqs)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 # ------------------------------------------------------- batched (G>1)

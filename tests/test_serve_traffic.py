@@ -3,14 +3,17 @@
 virtual-clock server model (sealed batches, serial service, monotonic
 completions), score parity with direct engine calls (coalesced rounds
 bitwise vs per-envelope), the wall-clock pump, queue-derived g_buckets,
-Poisson arrival statistics, and the replay report's steady-state
-zero-recompile guarantee."""
+Poisson arrival statistics, the replay report's steady-state
+zero-recompile guarantee, the pump's measured timeline and the queue's
+spans."""
+import contextlib
 import time
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.serve import (
     MicroBatchQueue,
     QueueConfig,
@@ -323,3 +326,205 @@ def test_replay_open_loop_sheds_under_overload(engine):
                                               max_pending=4), seed=8)
     assert rep["rejected"] > 0
     assert rep["served"] == 60 - rep["rejected"]
+
+
+# ------------------------------------------------- measured timeline
+class _Clock:
+    """A synthetic pump clock: reads do not advance it."""
+
+    def __init__(self, t: float):
+        self.t = t
+
+    def __call__(self) -> float:
+        return self.t
+
+
+class _AdvancingEngine:
+    """The real engine, with ``clock`` moved on by ``step`` seconds
+    inside every ``score_batch``."""
+
+    def __init__(self, engine, clock: _Clock, step: float):
+        self._engine, self._clock, self._step = engine, clock, step
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+    def score_batch(self, requests):
+        out = self._engine.score_batch(requests)
+        self._clock.t += self._step
+        return out
+
+
+def test_real_clock_pump_stamps_measured_start_and_end(engine):
+    """Under RealClockPump a flush's start and end are the pump clock's
+    readings when the flush begins and when score_batch returns, and the
+    ledger's and the histogram's queue delay is measured from that
+    start (modelled, the deadline flush would start at its deadline and
+    both would end after the engine's own wall time)."""
+    reqs = _uniform_requests(3, seed=51)
+    eng = ScoringEngine(engine._model)
+    eng.warm({eng.envelope(reqs[0])}, batch_sizes=eng.g_buckets)
+    clock = _Clock(1.0)
+    q = MicroBatchQueue(_AdvancingEngine(eng, clock, 0.25),
+                        QueueConfig(max_batch=2, max_delay_us=3000.0))
+    pump = RealClockPump(q, clock=clock)
+    led = obs.RunLedger(None)
+    prev = obs.set_ledger(led)
+    try:
+        pump.submit(reqs[0])
+        clock.t = 1.001
+        pump.submit(reqs[1])  # full flush, inline
+        clock.t = 1.3
+        pump.submit(reqs[2])
+        clock.t = 1.5  # past its 1.303 deadline
+        pump.start()
+        for _ in range(500):
+            if len(pump.completions()) == 3:
+                break
+            time.sleep(0.01)
+    finally:
+        pump.stop()
+        obs.set_ledger(prev)
+    c = sorted(q.completions, key=lambda c: c.ticket)
+    assert [x.reason for x in c] == ["full", "full", "deadline"]
+    assert [x.arrival for x in c] == [1.0, 1.001, 1.3]
+    assert c[0].started == c[1].started == 1.001
+    assert c[0].completed == c[1].completed == 1.001 + 0.25
+    assert c[2].started == 1.5 and c[2].completed == 1.5 + 0.25
+    delays = [r["queue_delay_us"] for r in led.events("serve_dispatch")]
+    assert delays == [pytest.approx(1e3), pytest.approx(2e5)]
+    assert q.stats._delay_hist.sum == pytest.approx(0.001 + 0.2)
+
+
+class _StubStats:
+    def __init__(self):
+        self.score_seconds, self.dispatches, self.slots = 0.0, 0, 0
+
+    def as_dict(self):
+        return {"dispatches": self.dispatches, "slots": self.slots}
+
+
+class _StubEngine:
+    """Fixed service time per dispatch (a power of two, so the modelled
+    sums are exact) and the request's own shape as its envelope."""
+
+    WALL = 2.0 ** -10
+
+    def __init__(self):
+        self.stats = _StubStats()
+
+    def envelope(self, r):
+        return (r.user_ids.shape[-1], r.ad_ids.shape[-1], r.ad_ids.shape[0])
+
+    def dispatch_context(self, reason, queue_delay_us):
+        return contextlib.nullcontext()
+
+    def score_batch(self, requests):
+        self.stats.score_seconds += self.WALL
+        self.stats.dispatches += 1
+        self.stats.slots += len(requests)
+        return [np.zeros(r.ad_ids.shape[0]) for r in requests]
+
+
+def _modelled_timeline(arrivals, envs, cfg, wall):
+    """The virtual-clock server written out: groups by envelope, full
+    flushes at their arrival, deadline flushes at oldest + delay, then a
+    drain; start = max(trigger, server free), end = start + wall.
+    {ticket: (reason, start, end, tickets flushed together)}."""
+    delay = cfg.max_delay_us * 1e-6
+    pending: dict = {}
+    busy = 0.0
+    out = {}
+
+    def flush(env, trigger, reason):
+        nonlocal busy
+        group = pending.pop(env)
+        start = max(trigger, busy)
+        busy = start + wall
+        tickets = tuple(t for t, _ in group)
+        for t in tickets:
+            out[t] = (reason, start, busy, tickets)
+
+    def due(now):
+        while True:
+            ready = sorted((g[0][1], env) for env, g in pending.items()
+                           if g[0][1] + delay <= now)
+            if not ready:
+                return
+            arr, env = ready[0]
+            flush(env, arr + delay, "deadline")
+
+    for t, (arr, env) in enumerate(zip(arrivals, envs)):
+        due(arr)
+        pending.setdefault(env, []).append((t, arr))
+        if len(pending[env]) >= cfg.max_batch:
+            flush(env, arr, "full")
+    due(arrivals[-1])
+    for env in sorted(pending, key=lambda e: pending[e][0][1]):
+        flush(env, arrivals[-1], "drain")
+    return out
+
+
+def test_replay_open_loop_keeps_the_modelled_timeline(monkeypatch):
+    """The virtual-clock replay's flush grouping, reasons, starts and
+    ends are the modelled server's, stamp for stamp."""
+    import repro.serve.traffic as traffic
+
+    reqs = synthetic_requests(120, num_features=D, k_user=(6, 7),
+                              k_ad=(4, 4), n_ads=(3, 4), seed=61)
+    cfg = QueueConfig(max_batch=4, max_delay_us=2000.0)
+    eng = _StubEngine()
+    seen = []
+
+    class Recording(MicroBatchQueue):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            seen.append(self)
+
+    monkeypatch.setattr(traffic, "MicroBatchQueue", Recording)
+    rep = replay_open_loop(eng, reqs, qps=1500.0, config=cfg, seed=62)
+    assert rep["served"] == 120 and rep["rejected"] == 0
+    arrivals = poisson_arrivals(120, 1500.0, seed=62)
+    want = _modelled_timeline(arrivals, [eng.envelope(r) for r in reqs],
+                              cfg, _StubEngine.WALL)
+    comps = seen[0].completions
+    groups: dict = {}
+    for c in comps:
+        groups.setdefault((c.started, c.reason), []).append(c.ticket)
+    got = {c.ticket: (c.reason, c.started, c.completed,
+                      tuple(groups[(c.started, c.reason)])) for c in comps}
+    assert got == want
+    assert {r for r, *_ in want.values()} == {"full", "deadline", "drain"}
+
+
+# ------------------------------------------------------------- spans
+def test_pump_full_flush_spans_nest_admit_flush_dispatch(engine):
+    reqs = _uniform_requests(2, seed=71)
+    eng = ScoringEngine(engine._model)
+    eng.warm({eng.envelope(reqs[0])}, batch_sizes=eng.g_buckets)
+    q = MicroBatchQueue(eng, QueueConfig(max_batch=2, max_delay_us=1e6))
+    tracer = obs.Tracer(enabled=True)
+    prev = obs.set_tracer(tracer)
+    try:
+        pump = RealClockPump(q)
+        for r in reqs:
+            pump.submit(r)  # the second runs the full flush inline
+        pump.stop()
+    finally:
+        obs.set_tracer(prev)
+    evs = [e for e in tracer.events() if e["ph"] == "X"]
+    by = {}
+    for e in evs:
+        by.setdefault(e["name"], []).append(e)
+    assert len(by["serve/admit"]) == 2
+    assert len(by["serve/flush"]) == len(by["serve/dispatch"]) == 1
+    admit = max(by["serve/admit"], key=lambda e: e["ts"])
+    flush, disp = by["serve/flush"][0], by["serve/dispatch"][0]
+    assert flush["args"] == {"reason": "full", "size": 2}
+
+    def inside(inner, outer):
+        return (outer["ts"] - 1e-6 <= inner["ts"] and inner["ts"] + inner["dur"]
+                <= outer["ts"] + outer["dur"] + 1e-6)
+
+    assert inside(flush, admit) and inside(disp, flush)
+    assert len({e["tid"] for e in evs}) == 1
