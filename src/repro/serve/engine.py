@@ -43,11 +43,22 @@ implied batch occupancy, per-envelope hit counts, compile count and
 seconds, and scoring wall seconds (used by ``benchmarks/bench_serve.py``
 and the ``repro.launch.serve`` smoke).
 
+One dispatch makes one transfer each way. The padded batch is ONE
+int32 buffer, ``[user ids | user values | ad ids | ad values]`` with the
+value blocks written through float32 views of it, so it crosses to the
+device as one explicit ``jax.device_put``; the executable slices it
+back into the four arrays in-program (a bitcast, so scores are bitwise
+what four separate arrays give). The scores' copy to the host is
+started (``copy_to_host_async``) as soon as the round is enqueued, so
+it runs when the round ends instead of after the host sees the end.
+
 With a tracer on (``repro.obs``), each dispatch is one ``serve/dispatch``
-span with four children in order: ``serve/pad`` (the padded numpy
-batch), ``serve/launch`` (the executable call: argument transfer and
-enqueue), ``serve/sync`` (``block_until_ready``) and ``serve/readback``
-(the scores' copy to the host, reshaped and sliced per request).
+span with four children in order: ``serve/pad`` (the packed numpy
+buffer), ``serve/launch`` (the one packed transfer, the executable call
+up to its return and the start of the scores' copy to the host),
+``serve/sync`` (``block_until_ready``: the wait for the round) and
+``serve/readback`` (``np.asarray`` of the copy already under way, the
+reshape and the per-request slices).
 """
 from __future__ import annotations
 
@@ -58,6 +69,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.serve.score import ScoreBundle, as_model, score_bundles
@@ -88,6 +100,11 @@ class EngineStats:
     process metrics registry: every field reads back out of a registry
     series, so the same numbers export through ``--metrics-out`` while
     the attribute/property API (and ``as_dict``) stays exactly as it was.
+
+    ``h2d_buffers`` (series ``serve_h2d_buffers``) counts the host-to-
+    device buffers the dispatches sent: one packed buffer a dispatch, so
+    it equals ``dispatches`` (four separate argument arrays would make it
+    four times that).
     """
 
     def __init__(self, registry=None):
@@ -98,6 +115,7 @@ class EngineStats:
         self._candidates = reg.counter("serve_candidates", **labels)
         self._dispatches = reg.counter("serve_dispatches", **labels)
         self._slots = reg.counter("serve_slots", **labels)
+        self._h2d_buffers = reg.counter("serve_h2d_buffers", **labels)
         self._compiles = reg.counter("serve_compiles", **labels)
         self._compile_s = reg.counter("serve_compile_seconds", **labels)
         self._score_s = reg.counter("serve_score_seconds", **labels)
@@ -111,12 +129,15 @@ class EngineStats:
         self._compile_s.inc(seconds)
 
     def note_dispatch(self, key: tuple, requests: int,
-                      candidates: int, wall_s: float) -> None:
+                      candidates: int, wall_s: float,
+                      h2d_buffers: int) -> None:
         """Book one AOT executable call: its padded envelope, the real
-        requests/candidates it carried, and its wall time."""
+        requests/candidates it carried, its wall time and the host-to-
+        device buffers it sent."""
         self._score_s.inc(wall_s)
         self._wall_hist.observe(wall_s)
         self._dispatches.inc(1.0)
+        self._h2d_buffers.inc(float(h2d_buffers))
         self._slots.inc(float(key[0]))
         self._requests.inc(float(requests))
         self._candidates.inc(float(candidates))
@@ -144,6 +165,10 @@ class EngineStats:
     @property
     def slots(self) -> int:
         return int(self._slots.value)
+
+    @property
+    def h2d_buffers(self) -> int:
+        return int(self._h2d_buffers.value)
 
     @property
     def compiles(self) -> int:
@@ -182,6 +207,7 @@ class EngineStats:
             "candidates": self.candidates,
             "dispatches": self.dispatches,
             "slots": self.slots,
+            "h2d_buffers": self.h2d_buffers,
             "occupancy": self.occupancy,
             "compiles": self.compiles,
             "compile_seconds": self.compile_seconds,
@@ -191,6 +217,26 @@ class EngineStats:
             "bucket_hits": {"x".join(map(str, k)): v
                             for k, v in self.bucket_hits.items()},
         }
+
+
+def _packed_size(key: tuple) -> int:
+    """Length of the int32 buffer one dispatch at ``key`` sends."""
+    g, ku, ka, n = key[:4]
+    return 2 * g * ku + 2 * g * n * ka
+
+
+def _unpack(packed, key: tuple, as_f32):
+    """The four padded arrays of a packed dispatch buffer, in order: user
+    ids (g, ku), user values (g, ku), ad ids (g*n, ka), ad values
+    (g*n, ka). The value blocks hold float32 bits; ``as_f32`` reads them
+    as float32 without changing a bit (a numpy view on the host, a
+    bitcast in the program)."""
+    g, ku, ka, n = key[:4]
+    u, a = g * ku, g * n * ka
+    return (packed[:u].reshape(g, ku),
+            as_f32(packed[u:2 * u]).reshape(g, ku),
+            packed[2 * u:2 * u + a].reshape(g * n, ka),
+            as_f32(packed[2 * u + a:]).reshape(g * n, ka))
 
 
 # The engine's envelope rounding and the autotune table's shape buckets
@@ -245,23 +291,21 @@ class ScoringEngine:
     def _executable(self, key: tuple):
         comp = self._compiled.get(key)
         if comp is None:
-            g, ku, ka, n = key[:4]
+            g, n = key[0], key[3]
             model, mode, dedup = self._model, self._mode, self._dedup
 
-            def fn(ui, uv, ai, av):
+            def fn(packed):
+                ui, uv, ai, av = _unpack(
+                    packed, key, lambda x: lax.bitcast_convert_type(x, jnp.float32))
                 bundle = ScoreBundle(
-                    ui, uv, ai, av,
-                    jnp.repeat(jnp.arange(g, dtype=jnp.int32), n))
+                    ui, uv, ai, av, jnp.repeat(jnp.arange(g, dtype=jnp.int32), n))
                 return score_bundles(model, bundle, mode=mode, dedup=dedup)
 
             t0 = time.perf_counter()
             with obs.get_tracer().span("serve/compile",
                                        envelope="x".join(map(str, key))):
                 comp = jax.jit(fn).lower(
-                    jax.ShapeDtypeStruct((g, ku), jnp.int32),
-                    jax.ShapeDtypeStruct((g, ku), jnp.float32),
-                    jax.ShapeDtypeStruct((g * n, ka), jnp.int32),
-                    jax.ShapeDtypeStruct((g * n, ka), jnp.float32),
+                    jax.ShapeDtypeStruct((_packed_size(key),), jnp.int32),
                 ).compile()
             self.stats.note_compile(time.perf_counter() - t0)
             self._compiled[key] = comp
@@ -295,23 +339,28 @@ class ScoringEngine:
                                   self._dtype))
 
     # -------------------------------------------------------------- scoring
-    def _pad_batch(self, requests: Sequence[BundleRequest], key: tuple):
+    def _pad_batch(self, requests: Sequence[BundleRequest],
+                   key: tuple) -> np.ndarray:
         """Stack same-envelope requests into the padded batch layout:
         request s owns user row s and candidate rows [s*n, (s+1)*n); pad
         candidate rows and pad bundle slots are all-pad-id (their scores
-        come out 0.5 and are sliced off)."""
-        g, ku, ka, n = key[:4]
-        ui = np.full((g, ku), self._pad_id, np.int32)
-        uv = np.zeros((g, ku), np.float32)
-        ai = np.full((g * n, ka), self._pad_id, np.int32)
-        av = np.zeros((g * n, ka), np.float32)
+        come out 0.5 and are sliced off).
+
+        The four arrays are views of ONE fresh int32 buffer (``_unpack``),
+        the values written through float32 views, so the batch crosses to
+        the device as one transfer."""
+        n = key[3]
+        packed = np.zeros(_packed_size(key), np.int32)  # zero bits = 0.0f
+        ui, uv, ai, av = _unpack(packed, key, lambda x: x.view(np.float32))
+        ui.fill(self._pad_id)
+        ai.fill(self._pad_id)
         for s, r in enumerate(requests):
             ui[s, :r.user_ids.shape[-1]] = r.user_ids
             uv[s, :r.user_vals.shape[-1]] = r.user_vals
             n_real, ka_real = r.ad_ids.shape
             ai[s * n:s * n + n_real, :ka_real] = r.ad_ids
             av[s * n:s * n + n_real, :ka_real] = r.ad_vals
-        return ui, uv, ai, av
+        return packed
 
     def _score_chunk(self, requests: Sequence[BundleRequest],
                      env: tuple[int, int, int]) -> list[np.ndarray]:
@@ -326,9 +375,12 @@ class ScoringEngine:
         t0 = time.perf_counter()
         with tracer.span("serve/dispatch", **args):
             with tracer.span("serve/pad"):
-                ui, uv, ai, av = self._pad_batch(requests, key)
-            with tracer.span("serve/launch"):  # argument transfer, enqueue
-                p = comp(ui, uv, ai, av)
+                packed = self._pad_batch(requests, key)
+            with tracer.span("serve/launch"):
+                # one transfer in, the enqueue, and the copy out started
+                # now so it runs as soon as the round ends
+                p = comp(jax.device_put(packed))
+                p.copy_to_host_async()
             with tracer.span("serve/sync"):
                 p = jax.block_until_ready(p)
             with tracer.span("serve/readback"):
@@ -337,7 +389,8 @@ class ScoringEngine:
                        for s, r in enumerate(requests)]
         wall = time.perf_counter() - t0
         n_cands = sum(r.ad_ids.shape[0] for r in requests)
-        self.stats.note_dispatch(key, len(requests), n_cands, wall)
+        self.stats.note_dispatch(key, len(requests), n_cands, wall,
+                                 h2d_buffers=1)
         led = obs.get_ledger()
         if led.enabled:
             reason, qdelay = self._dispatch_ctx

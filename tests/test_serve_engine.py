@@ -2,6 +2,7 @@
 with direct unpadded scoring, the steady-state ZERO-recompile guarantee
 under a randomized request replay, the stats ledger, and the spans of a
 dispatch."""
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -12,10 +13,12 @@ from repro.serve import (
     ScoreBundle,
     ScoringEngine,
     compress,
+    quantize,
     score_bundles,
     synthetic_requests,
 )
 from repro.serve.engine import _round_up
+from repro.serve.score import as_model
 
 D, M = 700, 3
 
@@ -161,6 +164,102 @@ def test_dispatch_span_has_four_children_in_order(theta):
     want = ScoringEngine(theta).score_batch(reqs)
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
+
+
+def _four_array_scores(model, requests, env, g) -> list[np.ndarray]:
+    """The dispatch as four separate padded arrays (user ids, user
+    values, ad ids, ad values) through the plain score layer: what one
+    engine dispatch of ``requests`` at (g, *env) computes."""
+    ku, ka, n = env
+    pad = as_model(model).num_features
+    ui = np.full((g, ku), pad, np.int32)
+    uv = np.zeros((g, ku), np.float32)
+    ai = np.full((g * n, ka), pad, np.int32)
+    av = np.zeros((g * n, ka), np.float32)
+    for s, r in enumerate(requests):
+        ui[s, :r.user_ids.shape[-1]] = r.user_ids
+        uv[s, :r.user_vals.shape[-1]] = r.user_vals
+        n_real, ka_real = r.ad_ids.shape
+        ai[s * n:s * n + n_real, :ka_real] = r.ad_ids
+        av[s * n:s * n + n_real, :ka_real] = r.ad_vals
+    sid = np.repeat(np.arange(g, dtype=np.int32), n)
+    p = np.asarray(jax.jit(lambda *a: score_bundles(model, ScoreBundle(*a)))(
+        ui, uv, ai, av, sid)).reshape(g, n)
+    return [p[s, :r.ad_ids.shape[0]] for s, r in enumerate(requests)]
+
+
+# ------------------------------------------------ one transfer each way
+@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+def test_packed_dispatch_bitwise_across_envelopes_and_g(theta, dtype):
+    """A wavefront of mixed envelopes whose groups land on every G
+    bucket: each request's packed-dispatch score is bitwise what it gets
+    alone (``score``) and what the four separate padded arrays give
+    through ``score_bundles`` at the same (G, envelope)."""
+    model = quantize(compress(theta)) if dtype == "int8" else theta
+    eng = ScoringEngine(model, k_buckets=(4, 8), n_buckets=(2, 4))
+    assert eng._dtype == dtype
+    shapes = [((3, 3), (2, 2), (2, 2)), ((6, 6), (3, 3), (2, 2)),
+              ((3, 3), (6, 6), (3, 3)), ((7, 7), (7, 7), (4, 4)),
+              ((8, 8), (3, 3), (4, 4))]
+    groups = [synthetic_requests(size, num_features=D, k_user=ku, k_ad=ka,
+                                 n_ads=n, seed=30 + i)
+              for i, (size, (ku, ka, n)) in enumerate(
+                  zip(eng.g_buckets, shapes))]
+    assert len({eng.envelope(g[0]) for g in groups}) == len(groups)
+    mixed = [r for g in groups for r in g]
+    order = np.random.default_rng(21).permutation(len(mixed))
+    wave = [mixed[i] for i in order]
+    got = eng.score_batch(wave)
+    assert eng.stats.dispatches == len(groups)
+    assert {k[0] for k in eng.stats.bucket_hits} == set(eng.g_buckets)
+    alone = ScoringEngine(model, k_buckets=(4, 8), n_buckets=(2, 4))
+    by_id = {id(r): p for r, p in zip(wave, got)}
+    for g, rs in zip(eng.g_buckets, groups):
+        direct = _four_array_scores(model, rs, eng.envelope(rs[0]), g)
+        for r, want in zip(rs, direct):
+            np.testing.assert_array_equal(by_id[id(r)], want)
+            np.testing.assert_array_equal(by_id[id(r)], alone.score(r))
+
+
+def test_dispatch_sends_only_the_packed_buffer(theta):
+    """With implicit host-to-device transfers disallowed, a warm
+    dispatch still runs: its one transfer is the explicit packed one.
+    The executable called on a host array is refused under the same
+    guard, so the guard is live here."""
+    reqs = synthetic_requests(3, num_features=D, k_user=(5, 5), k_ad=(4, 4),
+                              n_ads=(3, 3), seed=22)
+    eng = ScoringEngine(theta)
+    eng.warm({eng.envelope(reqs[0])}, batch_sizes=eng.g_buckets)
+    want = ScoringEngine(theta).score_batch(reqs)
+    key = (4, *eng.envelope(reqs[0]), "fp32")
+    comp = eng._executable(key)
+    with jax.transfer_guard_host_to_device("disallow"):
+        got = eng.score_batch(reqs)
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="host-to-device"):
+            comp(eng._pad_batch(reqs, key))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert eng.stats.h2d_buffers == eng.stats.dispatches == 1
+
+
+def test_one_h2d_buffer_per_dispatch_on_randomized_replay(theta):
+    """Every dispatch, whatever its envelope, G or entry point, sends
+    exactly one host-to-device buffer."""
+    rng = np.random.default_rng(23)
+    eng = ScoringEngine(theta)
+    reqs = synthetic_requests(30, num_features=D, seed=24)
+    for _ in range(3):
+        order = rng.permutation(len(reqs))
+        cut = int(rng.integers(1, len(reqs)))
+        eng.score_batch([reqs[i] for i in order[:cut]])
+        eng.score_many([reqs[i] for i in order[cut:cut + 3]])
+    wide = tuple(max(eng.envelope(r)[i] for r in reqs[:5]) for i in range(3))
+    eng.score_batch_at(reqs[:5], wide)
+    s = eng.stats
+    assert s.dispatches > 3
+    assert s.h2d_buffers == s.dispatches
+    assert s.as_dict()["h2d_buffers"] == s.dispatches
 
 
 # ------------------------------------------------------- batched (G>1)
