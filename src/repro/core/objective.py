@@ -12,8 +12,9 @@ common part of the dot products is computed once per session group and
 gathered per sample (Eq. 13).
 
 Sparse dispatch: batches shaped like ``repro.data.sparse.SparseCTRBatch``
-(padded-COO ``user_ids``/``ad_ids`` id lists instead of dense x) are
-detected structurally and routed to ``nll_sparse``, which runs on the
+(padded-COO ``user_ids``/``ad_ids`` id lists instead of dense x) or
+``FlatCTRBatch`` (sessionless ``ids``) are detected structurally and
+routed to ``nll_sparse``, which runs on the
 fused sparse kernel (``repro.kernels.lsplm_sparse_fused``) — Pallas
 gather-matmul on TPU, chunked jnp elsewhere, scatter-add custom VJP.
 """
@@ -87,9 +88,16 @@ def nll_common_feature(theta: jax.Array, batch: CommonFeatureBatch) -> jax.Array
     return _nll_from_logps(log_p1, log_p0, batch.y.astype(log_p1.dtype), batch.weight)
 
 
+def is_flat_batch(batch) -> bool:
+    """Structural check for sessionless padded COO (FlatCTRBatch)."""
+    return hasattr(batch, "ids") and hasattr(batch, "plan")
+
+
 def is_sparse_batch(batch) -> bool:
-    """Structural check for a padded-COO sparse batch (SparseCTRBatch)."""
-    return hasattr(batch, "ad_ids") and hasattr(batch, "user_ids")
+    """Structural check for a padded-COO sparse batch (SparseCTRBatch or
+    FlatCTRBatch)."""
+    return (hasattr(batch, "ad_ids") and hasattr(batch, "user_ids")) \
+        or is_flat_batch(batch)
 
 
 def nll_sparse(theta: jax.Array, batch, *, mode: str = "auto") -> jax.Array:
@@ -99,8 +107,16 @@ def nll_sparse(theta: jax.Array, batch, *, mode: str = "auto") -> jax.Array:
     backward is the transposed scatter into active Theta rows only —
     sort-free when the batch carries precomputed transpose plans
     (``repro.data.sparse.build_batch_plans``), scan-chunked otherwise.
+    A sessionless batch (``FlatCTRBatch``) runs one gather-matmul over
+    its rows, with no user side and no session gather.
     """
     tp = pad_theta(theta)
+    if is_flat_batch(batch):
+        z = sparse_gather_matmul(batch.ids, batch.vals, tp, mode=mode,
+                                 plan=batch.plan)
+        log_p1, log_p0 = logps_from_z(z)
+        return _nll_from_logps(log_p1, log_p0,
+                               batch.y.astype(log_p1.dtype), None)
     z_user = sparse_gather_matmul(batch.user_ids, batch.user_vals, tp,
                                   mode=mode,
                                   plan=getattr(batch, "user_plan", None))

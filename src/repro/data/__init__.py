@@ -12,6 +12,8 @@ from repro.data.common_feature import (  # noqa: F401
     shard_sessions,
 )
 from repro.data.sparse import (  # noqa: F401
+    FlatCTRBatch,
+    SlicedPlan,
     SparseCTRBatch,
     TransposePlan,
     build_batch_plans,

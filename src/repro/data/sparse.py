@@ -30,8 +30,18 @@ the per-step backward is pure gathers + segment sums — no sort, no
 scatter — on every backend (``repro.kernels.lsplm_sparse_scatter``).
 Batches without plans still work (scan-chunked scatter fallback).
 
-The common-feature trick composes: user ids are stored once per session
-(G, Ku) and gathered per sample, ad ids per sample (B, Ka).
+Two batch layouts train on this path:
+
+  * :class:`SparseCTRBatch`, the session layout (the common-feature
+    trick): user ids stored once per session (G, Ku) and gathered per
+    sample, ad ids per sample (B, Ka); two gathers an evaluation.
+  * :class:`FlatCTRBatch`, sessionless rows such as the public Criteo
+    schema's (``repro.data.criteo``): every impression carries all of
+    its K ids, nothing is shared; one gather an evaluation.
+
+On one device a side with more samples than one scatter call holds
+(``max_slice_samples``) is planned in slices of samples
+(``SlicedPlan``); the backward makes one call per slice.
 
 Distribution composes too: ``build_batch_plans(shards=...)`` /
 ``generate_sparse(shards=...)`` route the batch for a (data x model)
@@ -48,14 +58,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.objective import nll_sparse
 from repro.kernels.lsplm_sparse_fused.ops import (  # noqa: F401 (pad_theta re-exported)
     pad_theta,
     sparse_gather_matmul,
 )
+from repro.kernels.lsplm_sparse_scatter.lsplm_sparse_scatter import (
+    MAX_DZ_VMEM_BYTES,
+    max_slice_samples,
+)
 from repro.kernels.lsplm_sparse_scatter.ops import (  # noqa: F401 (re-export)
+    SlicedPlan,
     TransposePlan,
     build_transpose_plan,
+    plan_slices,
 )
 
 
@@ -74,6 +91,31 @@ class SparseCTRBatch(NamedTuple):
     ad_plan: TransposePlan | None = None
 
 
+class FlatCTRBatch(NamedTuple):
+    """Sessionless padded COO: each impression carries all of its ids."""
+
+    ids: jax.Array   # (B, K) int32, pad = num_features
+    vals: jax.Array  # (B, K)
+    y: jax.Array     # (B,)
+    num_features: int = 0  # d (static)
+    plan: TransposePlan | SlicedPlan | None = None
+
+
+def _plan_fields(batch) -> tuple[tuple[str, str, str], ...]:
+    """(side, ids field, plan field) of each id tensor the batch plans."""
+    if isinstance(batch, FlatCTRBatch):
+        return (("ids", "ids", "plan"),)
+    return (("user", "user_ids", "user_plan"), ("ad", "ad_ids", "ad_plan"))
+
+
+def plan_sides(batch) -> tuple[tuple[str, jax.Array, object], ...]:
+    """(side, ids, plan or None) of each id tensor of a session or a
+    sessionless batch: the one place outside the objective that knows
+    the two layouts."""
+    return tuple((side, getattr(batch, f), getattr(batch, p))
+                 for side, f, p in _plan_fields(batch))
+
+
 def _route(batch: "SparseCTRBatch", shards, data_shards: int):
     """Coerce ``shards`` (count or Partition) and route the batch for a
     (data x model) mesh — the one place the shards= paths share."""
@@ -85,26 +127,54 @@ def _route(batch: "SparseCTRBatch", shards, data_shards: int):
     return route_batch(batch, part, data_shards=data_shards)
 
 
-def build_batch_plans(batch: "SparseCTRBatch", *, shards=None,
-                      data_shards: int = 1):
+def build_batch_plans(batch, *, shards=None, data_shards: int = 1,
+                      max_dz_bytes: int = MAX_DZ_VMEM_BYTES):
     """Attach per-batch transpose plans (one argsort per id tensor, on
     the host, once) so every optimizer step's backward is sort-free.
-    Plans address the PADDED Theta (d + 1 rows, pad id == d).
+    Plans address the PADDED Theta (d + 1 rows, pad id == d). Takes a
+    :class:`SparseCTRBatch` or a :class:`FlatCTRBatch`.
+
+    On one device, an id tensor of more samples than one scatter call's
+    dz fits in ``max_dz_bytes`` of VMEM (the scatter kernel's ceiling)
+    is planned in slices of samples (:class:`SlicedPlan`, one scatter
+    call each); smaller ones get one plan, as before.
 
     With ``shards`` (a shard count or a ``repro.shard.Partition``) the
     planned batch is additionally ROUTED for a (data x model) mesh and a
     ``repro.shard.ShardedSparseBatch`` is returned instead: ids bucketed
     per id-range shard, the freshly built plans sliced per (data block,
     id range) — the argsort is NOT redone per shard — and stacked for
-    ``shard_map`` (see ``repro.shard``).
+    ``shard_map`` (see ``repro.shard``). Session batches only.
+
+    Recorded in ``repro.obs``: a ``train/plan`` span with args
+    ``impressions``, ``entries`` (non-pad id slots), ``distinct_rows``
+    and ``slices`` (scatter calls one backward makes), and the
+    ``train_plan_*`` counters of the same four, summed over batches.
     """
-    rows = batch.num_features + 1
-    batch = batch._replace(
-        user_plan=build_transpose_plan(
-            np.asarray(batch.user_ids), rows, pad_id=batch.num_features),
-        ad_plan=build_transpose_plan(
-            np.asarray(batch.ad_ids), rows, pad_id=batch.num_features),
-    )
+    if shards is not None and isinstance(batch, FlatCTRBatch):
+        raise ValueError("a sessionless FlatCTRBatch trains on one device; "
+                         "the sharded path takes session batches only")
+    d = batch.num_features
+    max_samples = None if shards is not None else max_slice_samples(max_dz_bytes)
+    with obs.get_tracer().span("train/plan") as span:
+        ids = {f: np.asarray(getattr(batch, f))
+               for _, f, _ in _plan_fields(batch)}
+        batch = batch._replace(**{
+            p: build_transpose_plan(ids[f], d + 1, pad_id=d,
+                                    max_samples=max_samples)
+            for _, f, p in _plan_fields(batch)})
+        flat = np.concatenate([a.reshape(-1) for a in ids.values()])
+        flat = flat[flat != d]
+        counts = dict(
+            impressions=int(np.asarray(batch.y).shape[0]),
+            entries=int(flat.size),
+            distinct_rows=int(np.count_nonzero(np.bincount(flat, minlength=d))),
+            slices=sum(len(plan_slices(plan))
+                       for _, _, plan in plan_sides(batch)))
+        span.set(**counts)
+    reg = obs.get_registry()
+    for name, value in counts.items():
+        reg.counter(f"train_plan_{name}").inc(value)
     if shards is None:
         return batch
     return _route(batch, shards, data_shards)
@@ -135,13 +205,16 @@ def sparse_loss_and_grad(theta: jax.Array, batch: SparseCTRBatch):
     return jax.value_and_grad(sparse_nll)(theta, batch)
 
 
-def sparse_predict(theta: jax.Array, batch: SparseCTRBatch) -> jax.Array:
+def sparse_predict(theta: jax.Array, batch) -> jax.Array:
     """p(y=1|x) for a session-structured sparse batch — delegates to the
     unified inference layer's session-shared path (``repro.serve``), the
     same code that serves online traffic (model polymorphic: pass a
-    pruned ``ServingArtifact`` instead of Theta and it still works)."""
+    pruned ``ServingArtifact`` instead of Theta and it still works). A
+    :class:`FlatCTRBatch` takes the flat path."""
     from repro.serve.score import predict
 
+    if isinstance(batch, FlatCTRBatch):
+        return sparse_predict_flat(theta, batch.ids, batch.vals)
     return predict(theta, batch)
 
 

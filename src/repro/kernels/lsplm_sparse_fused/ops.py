@@ -67,6 +67,7 @@ from repro.kernels.lsplm_sparse_fused.lsplm_sparse_fused import (
     lsplm_sparse_fused_int8_forward,
 )
 from repro.kernels.lsplm_sparse_scatter.ops import (
+    SlicedPlan,
     TransposePlan,
     dvals_planned,
     scatter_add_planned,
@@ -373,12 +374,13 @@ def sparse_gather_matmul(ids, vals, theta, *, mode: str = "auto",
                          block_n: int | None = None,
                          block_k: int | None = None,
                          chunk: int | None = None, dedup: bool = True,
-                         plan: TransposePlan | None = None) -> jax.Array:
+                         plan: TransposePlan | SlicedPlan | None = None
+                         ) -> jax.Array:
     """z = x @ Theta from padded COO, fused, custom-VJP'd. (N, K) -> (N, 2m).
 
     Pass ``plan`` (one ``build_transpose_plan`` per batch) to run the
     backward on the precomputed transpose layout — no sort/scatter in
-    the step. Without it the backward scans K-chunked scatter-adds.
+    the step; a ``SlicedPlan`` runs it once per slice of samples. Without it the backward scans K-chunked scatter-adds.
     ``dedup=False`` skips the kernel path's per-call duplicate-id
     collapse for batches known to be duplicate-free. block_n/block_k/
     chunk left at None resolve from the autotune table (``repro.tune``).

@@ -30,8 +30,11 @@ are scalars. dz is copied once into a VMEM scratch, each row right-padded
 to whole 128-lane tiles (the accumulator, the flush DMAs and the compact
 output use the same lane-aligned rows — the DMA engine cannot slice a
 2m-wide row). dz costs N * 512 B of VMEM at 2m <= 128: 8 MiB at N=16k;
-the scoped-VMEM limit is raised to fit it, and batches past
-``MAX_DZ_VMEM_BYTES`` must be sliced before planning.
+the scoped-VMEM limit is raised to fit it. A call takes at most
+``MAX_DZ_VMEM_BYTES`` of dz (131,072 samples at 2m <= 128): the batch
+planner (``data.sparse.build_batch_plans``) splits larger batches by
+samples into a ``plan.SlicedPlan`` of at most ``max_slice_samples()``
+each, and ``ops.scatter_add_planned`` makes one call per slice.
 
 The plan pads the sorted entries with at least one trailing sentinel
 (id == num_rows, never a real id): the sentinel both triggers the final
@@ -51,11 +54,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.lsplm_sparse_fused.lsplm_sparse_fused import (
+    LANES,
     lane_rows,
     varying_axes,
 )
 
 MAX_DZ_VMEM_BYTES = 64 << 20
+DZ_ROW_BYTES = LANES * 4  # one lane-aligned float32 dz row at 2m <= 128
+
+
+def max_slice_samples(max_dz_bytes: int = MAX_DZ_VMEM_BYTES) -> int:
+    """Samples whose dz one call holds in VMEM, at 2m <= 128."""
+    return max(1, max_dz_bytes // DZ_ROW_BYTES)
 
 
 def _kernel(row_ids_ref, sample_ref, vals_ref, dz_hbm, out_ref,
@@ -157,7 +167,8 @@ def lsplm_sparse_scatter_compact(
     if dz_bytes > MAX_DZ_VMEM_BYTES:
         raise ValueError(
             f"dz needs {dz_bytes:,} B of VMEM (> {MAX_DZ_VMEM_BYTES:,}); "
-            f"slice the batch before planning")
+            f"plan the batch in slices of at most max_slice_samples() "
+            f"samples (build_transpose_plan(..., max_samples=))")
     smem_block = pl.BlockSpec((block_e,), lambda i: (i,),
                               memory_space=pltpu.SMEM)
     compact = pl.pallas_call(

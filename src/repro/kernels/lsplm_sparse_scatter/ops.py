@@ -10,6 +10,9 @@ Public surface:
     half of the backward, read through the plan's sorted layout so the
     Theta row reads are id-ordered (cache/DMA friendly: duplicate ids
     are adjacent instead of strewn across the batch).
+  * ``plan`` may be a :class:`~.plan.SlicedPlan` (a batch split by
+    samples where one call's dz would not fit the kernel's VMEM): both
+    ops then run once per slice.
   * ``scatter_add_ref(ids, vals, dz, num_rows)``: the direct ``.at[].add``
     oracle the tests and benchmarks compare against.
 
@@ -34,6 +37,8 @@ sparsity (see ``benchmarks/bench_sparse_fused.py``).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -41,8 +46,10 @@ from repro.kernels.lsplm_sparse_scatter.lsplm_sparse_scatter import (
     lsplm_sparse_scatter_compact,
 )
 from repro.kernels.lsplm_sparse_scatter.plan import (  # noqa: F401  (re-export)
+    SlicedPlan,
     TransposePlan,
     build_transpose_plan,
+    plan_slices,
 )
 from repro.tune import table as tune
 
@@ -90,7 +97,7 @@ def _compact_classes(plan: TransposePlan, vals: jax.Array,
 
 
 def scatter_add_planned(
-    plan: TransposePlan,
+    plan: TransposePlan | SlicedPlan,
     vals: jax.Array,   # (N, K)
     dz: jax.Array,     # (N, 2m)
     *,
@@ -100,7 +107,14 @@ def scatter_add_planned(
     """dTheta (D, 2m) from the precomputed transpose plan. Race-free by
     construction: every output row is produced by exactly one segment.
     ``block_e=None`` resolves from the autotune table (``repro.tune``)
-    by the (entry-count, 2m) envelope; an explicit value wins."""
+    by the (entry-count, 2m) envelope; an explicit value wins. A
+    :class:`SlicedPlan` runs once per slice of samples, each result
+    densified and summed."""
+    if isinstance(plan, SlicedPlan):
+        parts = [scatter_add_planned(p, vals[lo:hi], dz[lo:hi], mode=mode,
+                                     block_e=block_e)
+                 for lo, hi, p in plan.slices()]
+        return functools.reduce(jnp.add, parts)
     if _use_kernel(mode):
         if block_e is None:
             env = tune.scatter_envelope(plan.num_kept, dz.shape[-1])
@@ -117,7 +131,7 @@ def scatter_add_planned(
 
 
 def dvals_planned(
-    plan: TransposePlan,
+    plan: TransposePlan | SlicedPlan,
     theta: jax.Array,  # (D, 2m)
     dz: jax.Array,     # (N, 2m)
     shape: tuple[int, int],
@@ -127,8 +141,13 @@ def dvals_planned(
     The Theta gather runs in id order (duplicates adjacent — the hot-id
     rows are read once per cache line instead of once per occurrence)
     and the result is permuted back to (N, K) with one gather; dropped
-    pad entries land on the appended zero slot.
+    pad entries land on the appended zero slot. A :class:`SlicedPlan`
+    does this per slice and concatenates the slices' rows.
     """
+    if isinstance(plan, SlicedPlan):
+        return jnp.concatenate(
+            [dvals_planned(p, theta, dz[lo:hi], (hi - lo, shape[1]))
+             for lo, hi, p in plan.slices()], axis=0)
     rows = _take(theta.astype(jnp.float32), plan.row_ids)
     dv_sorted = (rows * _take(dz.astype(jnp.float32),
                               plan.sample_sorted)).sum(axis=-1)

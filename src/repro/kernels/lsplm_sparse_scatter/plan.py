@@ -39,6 +39,12 @@ The same plan drives the jnp segment-sum path (`ops.scatter_add_planned`),
 the Pallas run-accumulate kernel (`lsplm_sparse_scatter.py`) and the
 fused forward/backward custom VJPs in ``lsplm_sparse_fused.ops``.
 
+A batch whose backward would not fit one scatter call is planned as a
+:class:`SlicedPlan`: the samples split into contiguous slices, each with
+its own plan over the same Theta rows (``build_transpose_plan(...,
+max_samples=)``). Each consumer runs once per slice and sums (dTheta) or
+concatenates (dvals) the results.
+
 Shapes in the plan are data-dependent (U, E' and the class split change
 with the batch), so jitted consumers recompile when the batch changes.
 That is the intended trade: the paper's OWLQN+ is full-batch — one batch,
@@ -114,6 +120,46 @@ class TransposePlan:
             raise ValueError(
                 f"plan was built for {self.num_rows} Theta rows, got "
                 f"{theta_rows}")
+
+
+@jax.tree_util.register_pytree_node_class
+class SlicedPlan:
+    """A batch's transpose plan split by samples: ``plans[i]`` is the
+    :class:`TransposePlan` of samples ``[bounds[i], bounds[i + 1])``,
+    each addressing the same ``num_rows`` Theta rows. The bounds are
+    static; each slice's backward is one scatter call."""
+
+    def __init__(self, plans, bounds):
+        self.plans = tuple(plans)
+        self.bounds = tuple(int(b) for b in bounds)
+        if len(self.bounds) != len(self.plans) + 1:
+            raise ValueError(f"{len(self.plans)} slices need "
+                             f"{len(self.plans) + 1} bounds, got {self.bounds}")
+
+    def tree_flatten(self):
+        return self.plans, self.bounds
+
+    @classmethod
+    def tree_unflatten(cls, bounds, plans):
+        return cls(plans, bounds)
+
+    def slices(self):
+        """(lo, hi, plan) per slice, in sample order."""
+        return [(lo, hi, p) for lo, hi, p
+                in zip(self.bounds[:-1], self.bounds[1:], self.plans)]
+
+    def validate(self, ids_shape: tuple, theta_rows: int) -> None:
+        n, k = ids_shape
+        if self.bounds[0] != 0 or self.bounds[-1] != n:
+            raise ValueError(f"plan slices cover samples [{self.bounds[0]}, "
+                             f"{self.bounds[-1]}), batch has {n}")
+        for lo, hi, p in self.slices():
+            p.validate((hi - lo, k), theta_rows)
+
+
+def plan_slices(plan) -> tuple:
+    """The per-call plans of ``plan``: its slices, or the plan itself."""
+    return plan.plans if isinstance(plan, SlicedPlan) else (plan,)
 
 
 def assemble_plan_from_sorted(
@@ -208,7 +254,8 @@ def build_transpose_plan(
     num_rows: int,
     *,
     pad_id: int | None = None,
-) -> TransposePlan:
+    max_samples: int | None = None,
+) -> TransposePlan | SlicedPlan:
     """Build the per-batch transpose plan on the host (numpy, no jit).
 
     Args:
@@ -219,6 +266,10 @@ def build_transpose_plan(
         their values are 0 by the padded-COO convention, so they
         contribute nothing and hot pad slots stop costing segment work.
         The pad row's cotangent is exactly 0 either way.
+      max_samples: if N exceeds it, the samples are split into the
+        fewest contiguous slices of at most ``max_samples`` (their sizes
+        differ by at most one) and a :class:`SlicedPlan` of one plan per
+        slice is returned.
 
     Cost: one argsort + unique over N*K int32 — tens of ms at production
     batch sizes, paid once per batch (not per optimizer step).
@@ -227,6 +278,12 @@ def build_transpose_plan(
     if ids.ndim != 2:
         raise ValueError(f"ids must be (N, K), got {ids.shape}")
     N, K = ids.shape
+    if max_samples is not None and N > max_samples:
+        n = -(-N // max(1, int(max_samples)))
+        bounds = [i * N // n for i in range(n + 1)]
+        return SlicedPlan([build_transpose_plan(ids[lo:hi], num_rows,
+                                                pad_id=pad_id)
+                           for lo, hi in zip(bounds[:-1], bounds[1:])], bounds)
     E = N * K
     flat = ids.reshape(-1).astype(np.int64)
     if flat.size and (flat.min() < 0 or flat.max() >= num_rows):

@@ -15,6 +15,11 @@ running on the fused sparse kernel — Pallas on TPU, chunked jnp on CPU):
   PYTHONPATH=src python -m repro.launch.train --sparse \
       --sparse-features 1000000 --sessions 1024 --regions 4 --iters 30
 
+Sessionless sparse mode on the public Criteo schema (39 one-hot fields,
+d = 998,960, no shared user side; repro.data.criteo), one device:
+  PYTHONPATH=src python -m repro.launch.train --sparse --schema criteo39 \
+      --impressions 196608 --regions 12 --lam 0.05 --beta 0.05 --iters 30
+
 Distributed sparse mode (the paper's worker/server split on the sparse
 path: samples over 'data', Theta rows over 'model' with id-range
 routing via repro.shard):
@@ -84,7 +89,13 @@ def train_sparse(args) -> SparseRun:
     split end to end (repro.shard): samples over 'data', Theta rows over
     'model' by id range, plan slices per shard, one z psum per step."""
     from repro.data import auc as auc_fn
-    from repro.data.sparse import generate_sparse, sparse_predict
+    from repro.data.sparse import (
+        build_batch_plans,
+        generate_sparse,
+        plan_sides,
+        plan_slices,
+        sparse_predict,
+    )
 
     distributed = args.mesh_data > 0 and args.mesh_model > 0
     if (args.mesh_data > 0) != (args.mesh_model > 0):
@@ -92,40 +103,59 @@ def train_sparse(args) -> SparseRun:
             "--mesh-data and --mesh-model must be set together (sparse "
             "mode shards samples x Theta rows as one (data, model) mesh)")
 
-    d, m = args.sparse_features, args.regions
-    user_range = (max(1, int(0.6 * d)), d)
-    train = generate_sparse(num_features=d, num_user_features_range=user_range,
-                            sessions=args.sessions, seed=args.seed + 1)
-    test = generate_sparse(num_features=d, num_user_features_range=user_range,
-                           sessions=max(args.sessions // 5, 32),
-                           seed=args.seed + 2)
+    m = args.regions
+    if args.schema == "criteo39":
+        from repro.data.criteo import NUM_FEATURES, generate_criteo
+
+        if distributed:
+            raise SystemExit("--schema criteo39 trains on one device: the "
+                             "sharded path takes session batches only")
+        d = NUM_FEATURES
+        train = generate_criteo(args.impressions, seed=args.seed + 1,
+                                with_plans=False)
+        test = generate_criteo(max(args.impressions // 5, 128),
+                               seed=args.seed + 2, with_plans=False)
+    else:
+        d = args.sparse_features
+        user_range = (max(1, int(0.6 * d)), d)
+        train = generate_sparse(num_features=d,
+                                num_user_features_range=user_range,
+                                sessions=args.sessions, seed=args.seed + 1,
+                                with_plans=False)
+        test = generate_sparse(num_features=d,
+                               num_user_features_range=user_range,
+                               sessions=max(args.sessions // 5, 32),
+                               seed=args.seed + 2, with_plans=False)
+    sides = plan_sides(train)
     theta0 = jnp.asarray(
         0.01 * np.random.default_rng(args.seed).normal(size=(d, 2 * m)),
         jnp.float32)
-    ku = train.user_ids.shape[-1]
-    ka = train.ad_ids.shape[-1]
-    apply_tuning_flags(args, batch_n=train.ad_ids.shape[0],
-                       batch_k=max(ku, ka))
+    apply_tuning_flags(args, batch_n=sides[-1][1].shape[0],
+                       batch_k=max(ids.shape[-1] for _, ids, _ in sides))
     if args.tune:
-        tune_job_shapes([(train.user_ids.shape[0], ku, d, m),
-                         (train.ad_ids.shape[0], ka, d, m)])
+        tune_job_shapes([(*ids.shape, d, m) for _, ids, _ in sides])
     kern = ("pipelined block-DMA kernel" if jax.default_backend() == "tpu"
             else "scan-chunked jnp fallback")
-    obs.log(f"sparse mode: d={d:,} columns, Theta {theta0.shape} "
-            f"({theta0.size:,} params), backend={jax.default_backend()} ({kern})")
-    for side, plan in (("user", train.user_plan), ("ad", train.ad_plan)):
-        obs.log(f"  {side} transpose plan: {plan.num_kept:,} entries, "
-                f"{plan.num_unique:,} unique ids, "
-                f"{len(plan.class_width)} popularity classes")
+    obs.log(f"sparse mode ({args.schema} schema): d={d:,} columns, Theta "
+            f"{theta0.shape} ({theta0.size:,} params), "
+            f"backend={jax.default_backend()} ({kern})")
+    # the training batch is planned once: here on one device (sliced by
+    # samples past one scatter call), below whole and routed on a mesh;
+    # the held-out batch is only scored, so it is never planned
+    if not distributed:
+        train = build_batch_plans(train)
+        for side, _, plan in plan_sides(train):
+            parts = plan_slices(plan)
+            for i, p in enumerate(parts):
+                name = side if len(parts) == 1 else f"{side} slice {i}"
+                obs.log(f"  {name} transpose plan: {p.num_kept:,} entries, "
+                        f"{p.num_unique:,} unique ids, "
+                        f"{len(p.class_width)} popularity classes")
 
     part = None
     if distributed:
         from repro.dist import shard_sparse_batch
-        from repro.shard import (
-            make_partition,
-            make_sharded_sparse_loss,
-            route_batch,
-        )
+        from repro.shard import make_partition, make_sharded_sparse_loss
 
         assert jax.device_count() >= args.mesh_data * args.mesh_model, (
             f"need {args.mesh_data * args.mesh_model} devices, "
@@ -135,8 +165,8 @@ def train_sparse(args) -> SparseRun:
                              f"--mesh-data {args.mesh_data}")
         mesh = make_debug_mesh(data=args.mesh_data, model=args.mesh_model)
         part = make_partition(d, args.mesh_model)
-        sbatch = shard_sparse_batch(
-            mesh, route_batch(train, part, data_shards=args.mesh_data))
+        sbatch = shard_sparse_batch(mesh, build_batch_plans(
+            train, shards=part, data_shards=args.mesh_data))
         opt = OWLQNPlus(make_sharded_sparse_loss(sbatch, mesh),
                         lam=args.lam, beta=args.beta)
         state = shard_state(opt.init(part.pad_rows(theta0)), mesh)
@@ -175,8 +205,8 @@ def train_sparse(args) -> SparseRun:
         jnp.asarray(jax.device_get(state.theta)))
     if args.drift_ref:
         p = np.asarray(sparse_predict(theta, test))
-        ids = np.concatenate([np.asarray(test.user_ids).ravel(),
-                              np.asarray(test.ad_ids).ravel()])
+        ids = np.concatenate([np.asarray(a).ravel()
+                              for _, a, _ in plan_sides(test)])
         ref = obs.capture_reference(p, np.asarray(test.y), ids,
                                     num_features=d)
         obs.log(f"drift reference (held-out test, {p.shape[0]} scores, "
@@ -383,6 +413,14 @@ def build_parser() -> argparse.ArgumentParser:
                          "fused sparse kernel (the paper's input format)")
     ap.add_argument("--sparse-features", type=int, default=1_000_000,
                     help="d for --sparse mode (feature columns)")
+    ap.add_argument("--schema", choices=("session", "criteo39"),
+                    default="session",
+                    help="--sparse: the batch layout: 'session' (user ids "
+                         "shared by a page view's ads) or 'criteo39' (the "
+                         "public Criteo schema: 39 one-hot fields per "
+                         "impression, d = 998,960, no sessions)")
+    ap.add_argument("--impressions", type=int, default=196_608,
+                    help="--schema criteo39: impressions in the batch")
     ap.add_argument("--stream", action="store_true",
                     help="streaming day-by-day training on the sparse path "
                          "(repro.stream): sliding-window minibatch OWLQN+ "
@@ -416,6 +454,9 @@ def main():
             "--block-n/--block-k/--chunk/--tune steer the sparse kernels; "
             "combine them with --sparse or --stream (the dense path has "
             "no tunable block sizes)")
+    if args.schema != "session" and (args.stream or not args.sparse):
+        raise SystemExit("--schema criteo39 trains on the sparse path; "
+                         "combine it with --sparse (not --stream)")
     mode = "stream" if args.stream else "sparse" if args.sparse else "dense"
     if args.drift_ref and mode == "dense":
         raise SystemExit(

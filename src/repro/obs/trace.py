@@ -40,6 +40,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> None:
         return None
 
+    def set(self, **args) -> None:
+        return None
+
 
 NULL_SPAN = _NullSpan()
 
@@ -62,6 +65,10 @@ class _Span:
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
+
+    def set(self, **args) -> None:
+        """Add args known only inside the span (recorded on exit)."""
+        self.args.update(args)
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()

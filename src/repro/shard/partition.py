@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data.sparse import SparseCTRBatch
+from repro.data.sparse import FlatCTRBatch, SlicedPlan, SparseCTRBatch
 from repro.kernels.lsplm_sparse_scatter.plan import TransposePlan
 
 
@@ -305,6 +305,14 @@ def route_batch(batch: SparseCTRBatch, part: Partition, *,
     """
     from repro.shard.plan_slicing import shard_plan_grid, stack_plans
 
+    if isinstance(batch, FlatCTRBatch):
+        raise ValueError("route_batch takes a session SparseCTRBatch: "
+                         "sessionless FlatCTRBatch rows train on one device")
+    if isinstance(batch.user_plan, SlicedPlan) or \
+            isinstance(batch.ad_plan, SlicedPlan):
+        raise ValueError("plans split by samples address one device's "
+                         "scatter; plan the batch with build_batch_plans("
+                         "batch, shards=...) instead")
     d = batch.num_features
     if part.num_rows != d:
         raise ValueError(f"partition covers {part.num_rows} rows, batch has "

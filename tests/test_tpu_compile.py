@@ -129,3 +129,38 @@ def test_sharded_loss_and_grad_compiles(topo):
     txt = _compiled_text(loss_and_grad, theta, shapes)
     assert "tpu_custom_call" in txt
     assert "all-reduce" in txt  # the z psum over 'model'
+
+
+
+def test_sliced_planned_scatter_compiles(one_chip):
+    """The Criteo-schema cell's backward: 196,608 rows planned in two
+    slices of samples, each slice's dz within the scatter kernel's VMEM
+    ceiling (the whole batch's is not). dz's VMEM depends on the rows
+    alone, so two ids a row stand in for the cell's 39 (XLA's compile of
+    the entry gathers grows with the entries, not the kernel's)."""
+    from repro.data.sparse import SlicedPlan, TransposePlan
+    from repro.kernels.lsplm_sparse_scatter.lsplm_sparse_scatter import (
+        max_slice_samples,
+    )
+    from repro.kernels.lsplm_sparse_scatter.ops import scatter_add_planned
+
+    d, n, k = 998_960, 196_608, 2
+    half = n // 2
+    assert half <= max_slice_samples() < n
+    s = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt,
+                                                         sharding=one_chip)
+
+    def plan(rows):  # the leaves' shapes; the kernel path reads no classes
+        e = rows * k
+        return TransposePlan(
+            class_src=(), class_samp=(), class_mask=(), class_width=(),
+            row_ids=s((e,)), sample_sorted=s((e,)), slot_sorted=s((e,)),
+            order=s((e,)), rank=s((e,)), inv_compact=s((d + 1,)),
+            inv_sorted=s((d + 1,)), num_rows=d + 1, num_entries=e,
+            num_kept=e, num_unique=200_000)
+
+    txt = _compiled_text(
+        lambda p, v, dz: scatter_add_planned(p, v, dz, mode="kernel"),
+        SlicedPlan([plan(half), plan(half)], (0, half, n)),
+        s((n, k), jnp.float32), s((n, M2), jnp.float32))
+    assert txt.count("tpu_custom_call") >= 2
